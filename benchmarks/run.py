@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.core import TPU_V5E, best_variant, comprehensive_tree, \
     enumerate_candidates
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 from repro.kernels.jacobi1d import FAMILY as JACOBI
 from repro.kernels.matadd import FAMILY as MATADD
 from repro.kernels.matmul import FAMILY as MATMUL
@@ -863,6 +864,7 @@ def main() -> None:
                     help="also write rows as machine-readable JSON "
                          "(scripts/check_bench.py gates CI on it)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     selected = None
     if args.only:

@@ -3,8 +3,9 @@
 compact -> rewrite.
 
 Loads each (family, machine) dispatch table (compiling it first when absent),
-times the top-k pre-ranked candidates per data-shape bucket on real or
-interpreted Pallas (deterministic seeds, trimmed-mean over repeats), fits the
+times the top-k pre-ranked candidates per data-shape bucket as Pallas,
+compiled on a TPU and interpreted on the CPU backend (deterministic seeds,
+trimmed-mean over repeats), fits the
 KLARAPTOR-style per-family calibration, computes the "few fit most" variant
 subset, and rewrites the table in place with the optional FORMAT_VERSION-2
 sections (``calibration``, ``measured_ranks``, ``compaction``).  The runtime
@@ -29,7 +30,8 @@ from repro.core.params import MACHINES                          # noqa: E402
 from repro.tuning import MeasureConfig, calibrate_table, \
     compact_table, measure_table                                # noqa: E402
 from repro.tuning.compact import compaction_summary             # noqa: E402
-from repro.tuning.measure import measure_shape, parse_bucket_key  # noqa: E402
+from repro.tuning.measure import interpret_pallas, measure_shape, \
+    parse_bucket_key                                            # noqa: E402
 
 
 def _load_or_compile(store, family, machine, quick):
@@ -68,8 +70,6 @@ def main(argv=None) -> int:
                     help="few-fit-most relative tolerance vs per-bucket best")
     ap.add_argument("--seed", type=int, default=0,
                     help="base seed for deterministic operand tensors")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="run kernels compiled (requires a real TPU backend)")
     ap.add_argument("--quick", action="store_true",
                     help="when compiling a missing table, build one bucket")
     ap.add_argument("--dry-run", action="store_true",
@@ -87,10 +87,10 @@ def main(argv=None) -> int:
     store = ArtifactStore(args.out)
     cfg = MeasureConfig(iters=args.iters, warmup=args.warmup, trim=args.trim,
                         max_dim=args.max_dim, top_k=args.top_k,
-                        seed=args.seed, interpret=not args.no_interpret)
+                        seed=args.seed)
     meta = {"iters": cfg.iters, "warmup": cfg.warmup, "trim": cfg.trim,
             "max_dim": cfg.max_dim, "top_k": cfg.top_k, "seed": cfg.seed,
-            "interpret": cfg.interpret}
+            "interpret": interpret_pallas()}
 
     failures = 0
     for name in names:

@@ -12,8 +12,9 @@ counter as the moral equivalent of the paper's register estimate.
 from __future__ import annotations
 
 import enum
-from dataclasses import dataclass, field
-from typing import Dict, Mapping, Tuple
+import functools
+from dataclasses import dataclass
+from typing import Any, Dict, Mapping
 
 
 class ParamKind(enum.Enum):
@@ -119,3 +120,33 @@ PAPER_M2050 = MachineDescription(
 MACHINES: Mapping[str, MachineDescription] = {
     m.name: m for m in (TPU_V5E, PAPER_M2050)
 }
+
+# ``jax.Device.device_kind`` -> machine.  A v5e reports "TPU v5 lite".
+DEVICE_KINDS: Mapping[str, MachineDescription] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def machine_for(device: Any) -> MachineDescription:
+    """The machine whose resources bind the case discussion on ``device``.
+
+    On the CPU backend that is ``TPU_V5E``, the modelled target that tests
+    and the dry run select for.  Any other device must be listed in
+    ``DEVICE_KINDS``: an unknown accelerator raises, so kernels are never
+    picked against another chip's limits."""
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return DEVICE_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no machine description for {device.platform} device kind "
+            f"{device.device_kind!r}; add it to repro.core.params."
+            f"DEVICE_KINDS") from None
+
+
+@functools.lru_cache(maxsize=1)
+def default_machine() -> MachineDescription:
+    """:func:`machine_for` this process's first device (resolved once)."""
+    import jax
+    return machine_for(jax.devices()[0])

@@ -17,7 +17,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -69,10 +68,10 @@ def compressed_psum_pod(grads: PyTree, mesh: Mesh, key: jax.Array) -> PyTree:
         spec = P(*(("pod",) + (None,) * (g.ndim - 1))) if g.ndim else P()
         # grads are replicated over pod on entry -> use P() in/out with the
         # reduction done on fully-addressable shards
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_ring_allreduce_int8, axis="pod"),
             mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         return fn(g.astype(jnp.float32), leaf_key).astype(g.dtype)
 
     leaves, treedef = jax.tree.flatten(grads)

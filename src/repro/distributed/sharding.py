@@ -10,8 +10,10 @@ mesh axes per architecture:
   and expert-sharded layouts.
 * **FSDP** (("pod","data")): the `embed` dim of weight matrices for the
   archs whose parameters cannot live TP-only (kimi-k2 1T, llama4-scout,
-  chameleon-34b).  With scan-over-layers this yields the per-layer
-  all-gather / reduce-scatter schedule of ZeRO-3.
+  chameleon-34b), and for every arch on a pure data-parallel mesh of more
+  than one device (no "model" axis divides the weights there).  With
+  scan-over-layers this yields the per-layer all-gather / reduce-scatter
+  schedule of ZeRO-3.
 * **ZeRO-1** optimizer extension: optimizer-state (and gradient-accumulator)
   leaves additionally shard their largest still-replicated divisible dim
   over ("pod","data").
@@ -40,10 +42,22 @@ def batch_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
+def uses_fsdp(cfg: ModelConfig, mesh: Mesh) -> bool:
+    """Shard weights over the batch axes (ZeRO-3)?  Yes for the archs
+    whose parameters need it at production scale, and on a mesh whose
+    "model" axis is 1 but whose batch axes are not: replicated f32
+    parameters, gradients and AdamW moments of hymba-1.5b do not fit a
+    16 GB v5e chip of a 2x2 host, even with the moments ZeRO-1-sharded."""
+    if cfg.name in FSDP_ARCHS:
+        return True
+    shards = int(np.prod([mesh.shape[a] for a in batch_axes(mesh)]))
+    return shards > 1 and mesh.shape.get("model", 1) == 1
+
+
 def rules_for(cfg: ModelConfig, mesh: Mesh) -> Dict[str, MeshAxes]:
     """Logical-axis -> mesh-axes mapping for this arch on this mesh."""
     batch = batch_axes(mesh)
-    fsdp = cfg.name in FSDP_ARCHS
+    fsdp = uses_fsdp(cfg, mesh)
     rules: Dict[str, MeshAxes] = {
         "layers": None,
         "embed": batch if fsdp else None,

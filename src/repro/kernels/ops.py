@@ -26,7 +26,7 @@ from typing import Mapping, Optional
 import jax
 
 from ..artifacts.dispatch import get_default_cache
-from ..core.params import MachineDescription, TPU_V5E
+from ..core.params import MachineDescription, default_machine
 from ..core.select import Candidate
 from . import ref
 from .flash_attention import FAMILY as FLASH_FAMILY
@@ -47,21 +47,24 @@ def _resolve_impl(impl: str) -> str:
 
 
 def select(family_name: str, data: Mapping[str, int],
-           machine: MachineDescription = TPU_V5E) -> Candidate:
-    """Resolve the kernel variant through the process-wide DispatchCache.
+           machine: Optional[MachineDescription] = None) -> Candidate:
+    """Resolve the kernel variant through the process-wide DispatchCache,
+    for ``machine`` or, by default, the machine this process runs on
+    (:func:`repro.core.params.default_machine`).
 
     Steady-state (the serving hot path) this is one lock-free frozen-plan
     lookup when the triple was frozen at warm-up, else one LRU lookup; a
     full miss falls back to the precompiled per-machine dispatch artifact,
     and only a shape never compiled offline pays for tree enumeration."""
-    return get_default_cache().best_variant(FAMILIES[family_name], machine,
+    return get_default_cache().best_variant(FAMILIES[family_name],
+                                            machine or default_machine(),
                                             data)
 
 
 # -- matmul -------------------------------------------------------------------
 
 def matmul(a: jax.Array, b: jax.Array, *, impl: str = "auto",
-           machine: MachineDescription = TPU_V5E,
+           machine: Optional[MachineDescription] = None,
            interpret: bool = False) -> jax.Array:
     impl = _resolve_impl(impl)
     if impl == "xla":
@@ -69,49 +72,52 @@ def matmul(a: jax.Array, b: jax.Array, *, impl: str = "auto",
     M, K = a.shape
     N = b.shape[1]
     fn = get_default_cache().warm_callable(
-        MATMUL_FAMILY, machine, (("M", M), ("N", N), ("K", K)), interpret)
+        MATMUL_FAMILY, machine or default_machine(),
+        (("M", M), ("N", N), ("K", K)), interpret)
     return fn(a, b)
 
 
 # -- matadd -------------------------------------------------------------------
 
 def matadd(a: jax.Array, b: jax.Array, *, impl: str = "auto",
-           machine: MachineDescription = TPU_V5E,
+           machine: Optional[MachineDescription] = None,
            interpret: bool = False) -> jax.Array:
     impl = _resolve_impl(impl)
     if impl == "xla":
         return ref.matadd(a, b)
     M, N = a.shape
     fn = get_default_cache().warm_callable(
-        MATADD_FAMILY, machine, (("M", M), ("N", N)), interpret)
+        MATADD_FAMILY, machine or default_machine(), (("M", M), ("N", N)),
+        interpret)
     return fn(a, b)
 
 
 # -- jacobi1d -------------------------------------------------------------------
 
 def jacobi1d(x: jax.Array, steps: int, *, impl: str = "auto",
-             machine: MachineDescription = TPU_V5E,
+             machine: Optional[MachineDescription] = None,
              interpret: bool = False) -> jax.Array:
     impl = _resolve_impl(impl)
     if impl == "xla":
         return ref.jacobi1d(x, steps)
     (n,) = x.shape
     fn = get_default_cache().warm_callable(
-        JACOBI_FAMILY, machine, (("N", n),), interpret)
+        JACOBI_FAMILY, machine or default_machine(), (("N", n),), interpret)
     return fn(x, steps)
 
 
 # -- transpose -----------------------------------------------------------------
 
 def transpose(a: jax.Array, *, impl: str = "auto",
-              machine: MachineDescription = TPU_V5E,
+              machine: Optional[MachineDescription] = None,
               interpret: bool = False) -> jax.Array:
     impl = _resolve_impl(impl)
     if impl == "xla":
         return ref.transpose(a)
     M, N = a.shape
     fn = get_default_cache().warm_callable(
-        TRANSPOSE_FAMILY, machine, (("M", M), ("N", N)), interpret)
+        TRANSPOSE_FAMILY, machine or default_machine(), (("M", M), ("N", N)),
+        interpret)
     return fn(a)
 
 
@@ -120,21 +126,23 @@ def transpose(a: jax.Array, *, impl: str = "auto",
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     impl: str = "auto",
-                    machine: MachineDescription = TPU_V5E,
+                    machine: Optional[MachineDescription] = None,
                     interpret: bool = False) -> jax.Array:
     impl = _resolve_impl(impl)
     if impl == "xla":
         return ref.flash_attention(q, k, v, causal=causal, window=window)
     h, sq, d = q.shape
     fn = get_default_cache().warm_callable(
-        FLASH_FAMILY, machine, (("SQ", sq), ("HD", d)), interpret)
+        FLASH_FAMILY, machine or default_machine(), (("SQ", sq), ("HD", d)),
+        interpret)
     return fn(q, k, v, causal=causal, window=window)
 
 
 # -- SSD scan --------------------------------------------------------------------
 
 def ssd_scan(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
-             impl: str = "auto", machine: MachineDescription = TPU_V5E,
+             impl: str = "auto",
+             machine: Optional[MachineDescription] = None,
              interpret: bool = False) -> jax.Array:
     impl = _resolve_impl(impl)
     if impl == "xla":
@@ -142,6 +150,6 @@ def ssd_scan(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
     seq, heads, hd = x.shape
     state = b.shape[-1]
     fn = get_default_cache().warm_callable(
-        SSD_FAMILY, machine,
+        SSD_FAMILY, machine or default_machine(),
         (("SQ", seq), ("HD", hd), ("STATE", state)), interpret)
     return fn(x, a, b, c)

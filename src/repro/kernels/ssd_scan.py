@@ -8,7 +8,8 @@ as the binding resource (the (C×C) score tile + state carry must fit).
 
 Grid layout: (heads, n_chunks) with the chunk axis innermost; TPU executes the
 grid sequentially, so the inter-chunk state lives in VMEM scratch across grid
-steps (same mechanism as the k-accumulation in matmul).
+steps (same mechanism as the k-accumulation in matmul).  Operands are laid
+out head-major so each block is a 2-D (chunk, ·) tile.
 """
 from __future__ import annotations
 
@@ -28,7 +29,8 @@ from .instantiate_cache import CachedInstantiationMixin
 
 
 def ssd_chunk(xc, ac, bc, cc, S_prev):
-    """One chunk of the SSD recurrence in matmul form (shared with models/).
+    """One chunk of the SSD recurrence in matmul form (the XLA path of
+    models/; :func:`_ssd_kernel` computes the same in 2-D tiles).
 
     xc: (C, hd)  ac: (C,)  bc/cc: (C, state)  S_prev: (state, hd)
     Returns (y: (C, hd), S_new: (state, hd)).  All f32.
@@ -51,51 +53,84 @@ def ssd_chunk(xc, ac, bc, cc, S_prev):
     return y_intra + y_inter, S_new
 
 
-def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, nc: int):
+def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, state_ref):
+    """One (head, chunk) grid step of :func:`ssd_chunk`, written in the ops
+    the TPU compiler lowers: 2-D tiles and matmuls only.  The prefix sums
+    of the log-decays come from a lower-triangular ones matmul, and the
+    transposed copy ``cum[i]`` from a ones-by-diagonal matmul, so the
+    kernel needs no 1-D vectors, cumsum or transposes."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    xc = x_ref[:, 0, :].astype(jnp.float32)
-    ac = a_ref[:, 0].astype(jnp.float32)
-    bc = b_ref[:, 0, :].astype(jnp.float32)
-    cc = c_ref[:, 0, :].astype(jnp.float32)
-    y, S_new = ssd_chunk(xc, ac, bc, cc, state_ref[...])
-    state_ref[...] = S_new
-    y_ref[:, 0, :] = y.astype(y_ref.dtype)
+    hi = jax.lax.Precision.HIGHEST
+    xc = x_ref[...].astype(jnp.float32)                # (C, hd)
+    la = la_ref[...].astype(jnp.float32)               # (C, 1) log a
+    bc = b_ref[...].astype(jnp.float32)                # (C, state)
+    cc = c_ref[...].astype(jnp.float32)
+    C, st = bc.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    tril = (row >= col).astype(jnp.float32)
+    diag = (row == col).astype(jnp.float32)
+    cum_t = jnp.dot(tril, jnp.broadcast_to(la, (C, C)),
+                    precision=hi)                      # [t, i] = cum[t]
+    cum_i = jnp.dot(jnp.ones((C, C), jnp.float32), diag * cum_t,
+                    precision=hi)                      # [t, i] = cum[i]
+    # mask BEFORE exp so the upper triangle cannot overflow to inf
+    L = jnp.exp(jnp.where(row >= col, cum_t - cum_i, -jnp.inf))
+    scores = jax.lax.dot_general(cc, bc, (((1,), (1,)), ((), ())),
+                                 precision=hi) * L     # (C, C)
+    cum_s = jnp.dot(tril, jnp.broadcast_to(la, (C, st)),
+                    precision=hi)                      # [t, s] = cum[t]
+    S_prev = state_ref[...]
+    y = (jnp.dot(scores, xc, precision=hi)
+         + jnp.dot(cc * jnp.exp(cum_s), S_prev, precision=hi))
+    w = jnp.exp(cum_s[C - 1:C, :] - cum_s)             # decay to chunk end
+    state_ref[...] = (jnp.exp(jnp.sum(la)) * S_prev
+                      + jax.lax.dot_general(bc * w, xc,
+                                            (((0,), (0,)), ((), ())),
+                                            precision=hi))
+    y_ref[...] = y.astype(y_ref.dtype)
 
 
 def pallas_ssd_scan(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
                     *, chunk: int, interpret: bool = False) -> jax.Array:
-    """x: (seq, heads, hd); a: (seq, heads); b,c: (seq, heads, state)."""
+    """x: (seq, heads, hd); a: (seq, heads); b,c: (seq, heads, state).
+
+    The operands go head-major into the kernel, so every block's last two
+    dims are (chunk, hd), (chunk, 1) or (chunk, state): the TPU tiling rule
+    wants them divisible by (8, 128) or equal to the array's."""
     seq, heads, hd = x.shape
     state = b.shape[-1]
     ck = min(chunk, seq)
     seq_p = -(-seq // ck) * ck
-    # pad with a=1 (identity decay), x=0 so padding contributes nothing
-    x = jnp.pad(x, ((0, seq_p - seq), (0, 0), (0, 0)))
-    a = jnp.pad(a, ((0, seq_p - seq), (0, 0)), constant_values=1.0)
-    b = jnp.pad(b, ((0, seq_p - seq), (0, 0), (0, 0)))
-    c = jnp.pad(c, ((0, seq_p - seq), (0, 0), (0, 0)))
-    nc = seq_p // ck
+    pad = seq_p - seq
+    # pad with a=1 (identity decay, log 0), x=0 so padding contributes
+    # nothing
+    xh = jnp.pad(x, ((0, pad), (0, 0), (0, 0))).transpose(1, 0, 2)
+    lah = jnp.log(jnp.pad(a, ((0, pad), (0, 0)), constant_values=1.0)
+                  ).T[:, :, None]
+    bh = jnp.pad(b, ((0, pad), (0, 0), (0, 0))).transpose(1, 0, 2)
+    ch = jnp.pad(c, ((0, pad), (0, 0), (0, 0))).transpose(1, 0, 2)
 
     y = pl.pallas_call(
-        functools.partial(_ssd_kernel, nc=nc),
-        grid=(heads, nc),
+        _ssd_kernel,
+        grid=(heads, seq_p // ck),
         in_specs=[
-            pl.BlockSpec((ck, 1, hd), lambda h, j: (j, h, 0)),
-            pl.BlockSpec((ck, 1), lambda h, j: (j, h)),
-            pl.BlockSpec((ck, 1, state), lambda h, j: (j, h, 0)),
-            pl.BlockSpec((ck, 1, state), lambda h, j: (j, h, 0)),
+            pl.BlockSpec((None, ck, hd), lambda h, j: (h, j, 0)),
+            pl.BlockSpec((None, ck, 1), lambda h, j: (h, j, 0)),
+            pl.BlockSpec((None, ck, state), lambda h, j: (h, j, 0)),
+            pl.BlockSpec((None, ck, state), lambda h, j: (h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((ck, 1, hd), lambda h, j: (j, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((seq_p, heads, hd), x.dtype),
+        out_specs=pl.BlockSpec((None, ck, hd), lambda h, j: (h, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((heads, seq_p, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((state, hd), jnp.float32)],
         interpret=interpret,
-    )(x, a, b, c)
-    return y[:seq]
+    )(xh, lah, bh, ch)
+    return y.transpose(1, 0, 2)[:seq]
 
 
 class SsdScanFamily(CachedInstantiationMixin):

@@ -7,13 +7,15 @@
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
-from repro.models import init_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import init_params
 from repro.obs import FlightRecorder, install
 from repro.plans import PlanStore
 from repro.runtime import ServeEngine
@@ -23,7 +25,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--full", action="store_true",
-                    help="full config (TPU-scale; default is smoke)")
+                    help="full config at published widths, weights "
+                         "stored in bf16 (TPU-scale; default is smoke)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -104,7 +107,9 @@ def main() -> None:
                          "age out first and are counted as dropped")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
+    enable_compile_cache()
+    cfg = (dataclasses.replace(get_config(args.arch), param_dtype="bfloat16")
+           if args.full else get_smoke_config(args.arch))
     if cfg.encoder is not None:
         raise SystemExit("enc-dec serving demo not wired for CLI; "
                          "see tests/test_serving.py")
@@ -113,7 +118,7 @@ def main() -> None:
         recorder = FlightRecorder(capacity=args.trace_capacity,
                                   sample_frozen_every=args.trace_sample)
         install(recorder)
-    params, _ = init_model(jax.random.PRNGKey(args.seed), cfg)
+    params = init_params(jax.random.PRNGKey(args.seed), cfg)
     plan_store = PlanStore(args.plan_dir) if args.plan_dir else None
     eng = ServeEngine(cfg, params, max_batch=args.max_batch,
                       max_len=args.max_len, page_size=args.block_size,

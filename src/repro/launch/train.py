@@ -12,21 +12,64 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLM, DataConfig
 from repro.distributed import sharding as dist
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.specs import grad_dtype_for, state_shardings, abstract_state
 from repro.models import init_model
+from repro.models.config import ModelConfig
 from repro.optim import make_optimizer, warmup_cosine
 from repro.runtime import TrainController, build_train_step
-from repro.runtime.steps import build_eval_step
+
+PyTree = Any
+
+
+def build_trainer(cfg: ModelConfig, mesh, *, lr: float, total_steps: int,
+                  microbatches: int, seed: int
+                  ) -> Tuple[PyTree, PyTree, Callable, Tuple[PyTree, PyTree]]:
+    """Sharded train state and the jitted step on ``mesh``.
+
+    Call under ``with mesh, dist.use_mesh_rules(mesh, rules)``.  The init
+    runs under jit with the state's shardings as ``out_shardings``, so every
+    leaf is built in place on its devices: the whole f32 state never sits
+    on one device first.  Returns ``(params, opt_state, step, (p_sh,
+    o_sh))``; ``step(params, opt_state, batch, i)`` donates the state."""
+    opt = make_optimizer(cfg.optimizer, warmup_cosine(lr, 10, total_steps))
+    params_sds, axes, opt_sds = abstract_state(cfg, opt)
+    p_sh, o_sh, _ = state_shardings(cfg, mesh, params_sds, axes, opt_sds)
+
+    def init(key):
+        params, _ = init_model(key, cfg)
+        return params, opt.init(params)
+
+    params, opt_state = jax.jit(init, out_shardings=(p_sh, o_sh))(
+        jax.random.PRNGKey(seed))
+    step_fn = build_train_step(cfg, opt, microbatches=microbatches,
+                               grad_dtype=grad_dtype_for(cfg))
+    jitted = jax.jit(step_fn, in_shardings=(p_sh, o_sh, None, None),
+                     out_shardings=(p_sh, o_sh, None),
+                     donate_argnums=(0, 1))
+    return params, opt_state, jitted, (p_sh, o_sh)
+
+
+def batch_at(cfg: ModelConfig, ds: SyntheticLM, step: int
+             ) -> Dict[str, jax.Array]:
+    """The stateless batch of ``step`` (plus stub encoder frames)."""
+    batch = {k: jnp.asarray(v) for k, v in ds.batch_at(step).items()}
+    if cfg.encoder is not None:
+        batch["enc_embeds"] = jax.random.normal(
+            jax.random.PRNGKey(step),
+            (ds.cfg.global_batch, cfg.encoder.seq_len, cfg.d_model),
+            jnp.float32)
+    return batch
 
 
 def main() -> None:
@@ -45,24 +88,15 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh()
     rules = dist.rules_for(cfg, mesh)
-    opt = make_optimizer(cfg.optimizer,
-                         warmup_cosine(args.lr, 10, args.steps))
-    step_fn = build_train_step(cfg, opt, microbatches=args.microbatches,
-                               grad_dtype=grad_dtype_for(cfg))
 
     with mesh, dist.use_mesh_rules(mesh, rules):
-        params, axes = init_model(jax.random.PRNGKey(args.seed), cfg)
-        opt_state = opt.init(params)
-        p_sh, o_sh, _ = state_shardings(cfg, mesh, params, axes, opt_state)
-        params = jax.device_put(params, p_sh)
-        opt_state = jax.device_put(opt_state, o_sh)
-        jitted = jax.jit(step_fn, in_shardings=(p_sh, o_sh, None, None),
-                         out_shardings=(p_sh, o_sh, None),
-                         donate_argnums=(0, 1))
-
+        params, opt_state, jitted, (p_sh, o_sh) = build_trainer(
+            cfg, mesh, lr=args.lr, total_steps=args.steps,
+            microbatches=args.microbatches, seed=args.seed)
         ds = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=args.seq_len,
                                     global_batch=args.global_batch,
                                     seed=args.seed))
@@ -70,14 +104,9 @@ def main() -> None:
 
         def run_step(state, step):
             params, opt_state = state
-            batch = {k: jnp.asarray(v) for k, v in ds.batch_at(step).items()}
-            if cfg.encoder is not None:
-                batch["enc_embeds"] = jax.random.normal(
-                    jax.random.PRNGKey(step),
-                    (args.global_batch, cfg.encoder.seq_len, cfg.d_model),
-                    jnp.float32)
             params, opt_state, metrics = jitted(
-                params, opt_state, batch, jnp.asarray(step, jnp.int32))
+                params, opt_state, batch_at(cfg, ds, step),
+                jnp.asarray(step, jnp.int32))
             return (params, opt_state), {k: float(v)
                                          for k, v in metrics.items()}
 

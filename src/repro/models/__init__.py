@@ -3,7 +3,7 @@ from .config import (EncoderConfig, ModelConfig, MoEConfig, SSMConfig,
                      SHAPES, SHAPES_BY_NAME, ShapeConfig)
 from .transformer import (block_apply, cache_spec_axes, decode_step, encode,
                           forward, init_cache, init_layer, init_model,
-                          init_paged_cache, paged_copy_block,
+                          init_params, init_paged_cache, paged_copy_block,
                           paged_decode_step, paged_prefill_chunk,
                           param_count, prefill)
 
@@ -11,6 +11,6 @@ __all__ = [
     "EncoderConfig", "ModelConfig", "MoEConfig", "SSMConfig", "SHAPES",
     "SHAPES_BY_NAME", "ShapeConfig", "block_apply", "cache_spec_axes",
     "decode_step", "encode", "forward", "init_cache", "init_layer",
-    "init_model", "init_paged_cache", "paged_copy_block",
+    "init_model", "init_params", "init_paged_cache", "paged_copy_block",
     "paged_decode_step", "paged_prefill_chunk", "param_count", "prefill",
 ]

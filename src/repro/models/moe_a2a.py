@@ -31,7 +31,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
@@ -142,14 +141,14 @@ def moe_block_a2a(p: Params, x: jax.Array, cfg: ModelConfig, *,
         return jnp.pad(w, ((0, E_pad - E), (0, 0), (0, 0)))
 
     espec = P(axes if len(axes) > 1 else axes[0])
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes if len(axes) > 1 else axes[0], None, None),
                   P(None, None),
                   P(*espec, None, None), P(*espec, None, None),
                   P(*espec, None, None)),
         out_specs=(P(axes if len(axes) > 1 else axes[0], None, None), P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(xg, p["router"],
                 pad_w(p["wi"]), pad_w(p["wg"]), pad_w(p["wo"]))
     return y.reshape(B, S, d), aux

@@ -109,6 +109,15 @@ def init_model(key, cfg: ModelConfig) -> Tuple[Params, Axes]:
     return p, a
 
 
+def init_params(key, cfg: ModelConfig) -> Params:
+    """:func:`init_model`'s parameters, built under jit.
+
+    Each leaf is drawn in f32 and cast to ``cfg.param_dtype`` inside one
+    compiled program, so a bf16 config never holds its whole f32 tree on
+    the device (Qwen1.5-4B's would take the whole of a 16 GB chip)."""
+    return jax.jit(lambda k: init_model(k, cfg)[0])(key)
+
+
 def param_count(params: Params) -> int:
     return sum(x.size for x in jax.tree.leaves(params))
 

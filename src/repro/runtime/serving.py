@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.params import MachineDescription, TPU_V5E
+from ..core.params import MachineDescription, default_machine
 from ..models import (init_paged_cache, paged_copy_block, paged_decode_step,
                       paged_prefill_chunk)
 from ..models.config import ModelConfig
@@ -81,7 +81,7 @@ PyTree = Any
 
 
 def warm_kernel_dispatch(cfg: ModelConfig, *,
-                         machine: MachineDescription = TPU_V5E,
+                         machine: Optional[MachineDescription] = None,
                          max_len: int = 512,
                          page_size: int = 0,
                          freeze: bool = True,
@@ -132,6 +132,7 @@ def warm_kernel_dispatch(cfg: ModelConfig, *,
     from ..plans.loader import warm_from_plan
     from ..plans.trace import trace_warm_set
     cache = get_default_cache()
+    machine = machine or default_machine()
 
     if freeze and plan_store is not False:
         picks = warm_from_plan(cfg, machine=machine, max_len=max_len,
@@ -165,6 +166,34 @@ def warm_kernel_dispatch(cfg: ModelConfig, *,
             picks[label] = {"candidate": ent.candidate,
                             "rank_source": ent.source}
     return picks
+
+
+def engine_steps(cfg: ModelConfig) -> Tuple[Any, Any]:
+    """The engine's (prefill-chunk, decode) step functions, before jit.
+
+    ``prefill(params, tokens (1, C), cache, start, block_table (1, nblk),
+    slot) -> (seed token (1, 1), cache)`` and ``decode(params, last_tok
+    (B, 1), cache, index (B,), block_tables (B, nblk), mask (B,)) ->
+    (tokens (B, 1), last_tok, cache)``.  The engine jits both with the
+    cache donated; compile tests lower the same functions."""
+
+    def prefill(params, tokens, cache, start, block_table, slot):
+        logits, cache = paged_prefill_chunk(params, cfg, tokens, cache,
+                                            start, block_table, slot)
+        # sample in-jit: the seed token stays device-resident until the
+        # commit barrier materializes it
+        return greedy_sample(logits), cache
+
+    def decode(params, last_tok, cache, index, block_tables, mask):
+        logits, cache = paged_decode_step(params, cfg, last_tok, cache,
+                                          index, block_tables, ssm_mask=mask)
+        nxt = greedy_sample(logits)
+        # chain last_tok device-side: decoding rows advance to their
+        # sampled token, everything else (dead rows, mid-prefill rows)
+        # keeps its value — no host sync between ticks
+        return nxt, jnp.where(mask[:, None], nxt, last_tok), cache
+
+    return prefill, decode
 
 
 @dataclass
@@ -201,7 +230,7 @@ class ServeEngine:
                  deadline_ms: Optional[float] = None,
                  watchdog: bool = True,
                  clock: faults.Clock = faults.default_clock,
-                 machine: MachineDescription = TPU_V5E):
+                 machine: Optional[MachineDescription] = None):
         if cfg.encoder is not None:
             raise ValueError("ServeEngine does not serve encoder-decoder "
                              "configs")
@@ -213,7 +242,9 @@ class ServeEngine:
         self.max_len = max_len
         self.page_size = page_size
         self.async_depth = async_depth
-        self.machine = machine
+        # the case discussion binds the resources of the chip this process
+        # runs on (TPU_V5E, the modelled target, on the CPU backend)
+        self.machine = machine = machine or default_machine()
         # graceful degradation (repro.runtime.faults + DispatchCache.demote):
         # a recoverable failure inside a guarded tick stage demotes a frozen
         # kernel pick and retries once; a second failure poisons the affected
@@ -272,27 +303,11 @@ class ServeEngine:
         self._degrade_rr = 0                 # round-robin over frozen triples
         self._rejected: List[Request] = []   # shed at submit, surfaced by step
 
-        def _prefill(params, tokens, cache, start, block_table, slot):
-            logits, cache = paged_prefill_chunk(params, cfg, tokens, cache,
-                                                start, block_table, slot)
-            # sample in-jit: the seed token stays device-resident until the
-            # commit barrier materializes it
-            return greedy_sample(logits), cache
-
-        def _decode(params, last_tok, cache, index, block_tables, mask):
-            logits, cache = paged_decode_step(params, cfg, last_tok, cache,
-                                              index, block_tables,
-                                              ssm_mask=mask)
-            nxt = greedy_sample(logits)
-            # chain last_tok device-side: decoding rows advance to their
-            # sampled token, everything else (dead rows, mid-prefill rows)
-            # keeps its value — no host sync between ticks
-            return nxt, jnp.where(mask[:, None], nxt, last_tok), cache
-
         # one compile per quantized chunk length; decode + CoW copy are
         # shape-stable
-        self._prefill = jax.jit(_prefill, donate_argnums=(2,))
-        self._decode = jax.jit(_decode, donate_argnums=(2,))
+        prefill, decode = engine_steps(cfg)
+        self._prefill = jax.jit(prefill, donate_argnums=(2,))
+        self._decode = jax.jit(decode, donate_argnums=(2,))
         self._copy = jax.jit(paged_copy_block, donate_argnums=(0,))
         self.cache = init_paged_cache(cfg, num_blocks, page_size, max_batch)
         self.last_tok = jnp.zeros((max_batch, 1), jnp.int32)

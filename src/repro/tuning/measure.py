@@ -4,8 +4,9 @@ KLARAPTOR-style calibration).
 Given a compiled dispatch table (:mod:`repro.artifacts.compile`), this
 module re-runs the top-k pre-ranked candidates of every data-shape bucket as
 *actual kernels* — ``family.instantiate(plan, assignment)`` under ``jax``,
-with ``interpret=True`` on hosts without a TPU so the same harness runs on
-the CPU CI container — and records a trimmed-mean wall time per candidate.
+interpreted only where the backend is the CPU (:func:`interpret_pallas`) so
+the same harness runs on the CPU CI container and times compiled kernels on
+a TPU — and records a trimmed-mean wall time per candidate.
 
 Invariants:
 
@@ -115,7 +116,13 @@ class MeasureConfig:
     max_dim: int = 256      # clamp_data bound for measured shapes
     top_k: int = 8          # candidates measured per bucket (prefix of table)
     seed: int = 0           # base PRNG seed (mixed with family+bucket)
-    interpret: bool = True  # interpreted Pallas (CPU hosts); False on TPU
+
+
+def interpret_pallas() -> bool:
+    """Pallas is interpreted only on a CPU backend: on a TPU the harness
+    times the compiled kernel, never the interpreter."""
+    import jax
+    return jax.default_backend() == "cpu"
 
 
 @dataclass
@@ -188,7 +195,8 @@ def default_timer(family: FamilySpec, plan: KernelPlan,
     import time
 
     import jax
-    fn = family.instantiate(plan, dict(assignment), interpret=cfg.interpret)
+    fn = family.instantiate(plan, dict(assignment),
+                            interpret=interpret_pallas())
     seed = _seed_for(family.name, repr(sorted(data.items())), cfg.seed)
     args, kwargs = _build_inputs(family.name, data, seed)
     for _ in range(max(0, cfg.warmup)):

@@ -11,13 +11,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed import sharding as dist
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_basic_rules():
@@ -47,13 +47,26 @@ def test_spec_dedup_and_divisibility():
 
 def test_spec_nondivisible_falls_back():
     cfg = get_config("mamba2-130m")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = dict(dist.rules_for(cfg, mesh))
     rules["vocab"] = "model"
     with dist.use_mesh_rules(mesh, rules):
         # vocab 50280 % 1 == 0 on a 1-device mesh: kept
         s1 = dist.spec_for(("vocab", "embed"), rules, (50280, 768))
         assert s1 == P("model")
+
+
+def test_fsdp_on_pure_data_parallel_meshes():
+    """Weights shard over the batch axes where no "model" axis can hold
+    them (hymba-1.5b's f32 train state on a v5e 2x2), and only there."""
+    from jax.sharding import AbstractMesh
+    cfg = get_config("hymba-1.5b")
+    dp4 = AbstractMesh((4, 1), ("data", "model"))
+    assert dist.uses_fsdp(cfg, dp4)
+    assert dist.rules_for(cfg, dp4)["embed"] == ("data",)
+    assert not dist.uses_fsdp(cfg, AbstractMesh((2, 2), ("data", "model")))
+    assert not dist.uses_fsdp(cfg, _mesh11())
+    assert dist.uses_fsdp(get_config("kimi-k2-1t-a32b"), _mesh11())
 
 
 def test_constrain_noop_without_mesh():
@@ -89,7 +102,8 @@ COMPRESS_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
     from repro.distributed import compressed_psum_pod
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     key = jax.random.PRNGKey(0)
     grads = {"w": jax.random.normal(key, (64, 64)),
              "b": jax.random.normal(jax.random.PRNGKey(1), (17,))}

@@ -13,6 +13,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.distributed import sharding as dist
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import (abstract_state, cache_specs, probe_config,
                                 skip_reason, state_shardings,
                                 train_batch_specs)
@@ -30,7 +31,7 @@ def _small_shape(kind):
                                   "hymba_1p5b"])
 def test_train_lowering_compiles(arch):
     cfg = get_smoke_config(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = dist.rules_for(cfg, mesh)
     opt = adamw(constant(1e-3))
     shape = _small_shape("train")
@@ -54,7 +55,7 @@ def test_train_lowering_compiles(arch):
 @pytest.mark.parametrize("arch", ["yi_6b", "hymba_1p5b"])
 def test_serve_lowering_compiles(arch):
     cfg = get_smoke_config(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = dist.rules_for(cfg, mesh)
     with mesh, dist.use_mesh_rules(mesh, rules):
         params_sds, axes, _ = abstract_state(cfg, None)

@@ -64,10 +64,10 @@ def test_iota_replica_groups():
 def test_real_lowering_collectives():
     """A psum under shard_map on a 1-device mesh lowers; the parser runs on
     real HLO without crashing (byte count may be 0 on 1 device)."""
-    mesh = jax.make_mesh((1,), ("x",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    f = shard_map(lambda x: jax.lax.psum(x, "x"), mesh=mesh,
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",))
+    f = jax.shard_map(lambda x: jax.lax.psum(x, "x"), mesh=mesh,
                   in_specs=P("x"), out_specs=P())
     hlo = jax.jit(f).lower(jnp.ones((4, 4))).compile().as_text()
     rep = H.collective_report(hlo, total_devices=1)

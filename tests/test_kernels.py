@@ -129,6 +129,23 @@ def test_ssd_scan(seq, heads, hd, state):
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("chunk", [64, 128, 256])
+def test_pallas_ssd_scan_every_chunk(chunk):
+    """Every chunk variant of the head-major kernel, over several heads and
+    several chunks with a ragged tail (padding), matches the sequential
+    oracle in interpret mode."""
+    from repro.kernels.ssd_scan import pallas_ssd_scan
+    seq, heads, hd, state = 600, 3, 64, 128
+    x = _rand(23, (seq, heads, hd))
+    a = jax.nn.sigmoid(_rand(24, (seq, heads))) * 0.9 + 0.05
+    b = _rand(25, (seq, heads, state)) * 0.3
+    c = _rand(26, (seq, heads, state)) * 0.3
+    out = pallas_ssd_scan(x, a, b, c, chunk=chunk, interpret=True)
+    want = ref.ssd_scan(x, a, b, c)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+
+
 def test_ssd_chunk_equals_stepwise():
     """The matmul-form chunk recurrence == naive per-token recurrence."""
     C, hd, st_ = 64, 16, 8
@@ -165,3 +182,18 @@ def test_selected_variant_is_feasible_and_deterministic():
     # VMEM constraint holds under v5e binding
     vmem = 2 * 2 * (bm * bk + bk * bn * s) + 4 * bm * bn * s * 2
     assert vmem <= TPU_V5E.vmem_bytes
+
+
+def test_machine_resolved_from_device_kind():
+    """A v5e binds TPU_V5E, the CPU backend binds the modelled TPU_V5E, and
+    a TPU kind with no description raises instead of borrowing v5e's
+    limits."""
+    from types import SimpleNamespace
+    from repro.core.params import TPU_V5E, default_machine, machine_for
+    assert machine_for(jax.devices()[0]) is TPU_V5E        # CPU backend
+    assert default_machine() is TPU_V5E
+    assert machine_for(SimpleNamespace(platform="tpu",
+                                       device_kind="TPU v5 lite")) is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v9 unknown"):
+        machine_for(SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v9 unknown"))

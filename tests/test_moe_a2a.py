@@ -19,6 +19,7 @@ SCRIPT = textwrap.dedent("""
     from repro.models.config import ModelConfig, MoEConfig
     from repro.models.moe import init_moe, moe_block
     from repro.models.moe_a2a import moe_block_a2a
+    from repro.launch.mesh import make_mesh
 
     cfg = ModelConfig(
         name="a2a-test", layers=1, d_model=32, heads=4, kv_heads=2,
@@ -26,7 +27,7 @@ SCRIPT = textwrap.dedent("""
         moe=MoEConfig(num_experts=6, top_k=2, d_ff_expert=48,
                       capacity_factor=64.0))     # dropless => paths agree
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = dist.rules_for(cfg, mesh)
     p, _ = init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32), jnp.float32)

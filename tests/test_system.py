@@ -107,3 +107,37 @@ def test_quickstart_example_runs():
         [sys.executable, os.path.join(root, "examples", "quickstart.py")],
         env=env, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr
+
+
+def test_init_params_matches_init_model_in_param_dtype():
+    """The jitted init draws the same weights as init_model and stores the
+    >=2-D ones in the config's param dtype (norm scales stay f32)."""
+    import dataclasses
+    from repro.models import init_params
+    cfg = dataclasses.replace(get_smoke_config("qwen1.5-4b"),
+                              param_dtype="bfloat16")
+    got = init_params(jax.random.PRNGKey(3), cfg)
+    want, _ = init_model(jax.random.PRNGKey(3), cfg)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        assert g.dtype == (jnp.bfloat16 if w.ndim >= 2 else jnp.float32)
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The env var wins and nothing else is set; without it the cache is
+    one fixed directory in the checkout."""
+    from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == str(CHECKOUT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert (CHECKOUT / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
