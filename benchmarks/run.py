@@ -1,4 +1,4 @@
-"""Benchmark harness — one function per paper table/figure + roofline dump.
+"""Benchmark harness — one function per paper table/figure.
 
 Wall-clock numbers are CPU-XLA (the container's only runtime) and are used
 for *relative* variant comparisons; the TPU-side ranking column comes from
@@ -855,11 +855,9 @@ BENCH_GROUPS = (
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma-separated group names to run "
-                         f"(one of: {', '.join(n for n, _ in BENCH_GROUPS)}); "
-                         "implies --skip-roofline")
+                         f"(one of: {', '.join(n for n, _ in BENCH_GROUPS)})")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as machine-readable JSON "
                          "(scripts/check_bench.py gates CI on it)")
@@ -891,22 +889,6 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=1, sort_keys=True)
         print(f"# wrote {len(rows)} rows to {args.json}", flush=True)
-
-    if not args.skip_roofline and selected is None:
-        print("\n# Roofline (from dry-run artifacts; see EXPERIMENTS.md)")
-        try:
-            from . import roofline
-            rows = roofline.full_table()
-            ok = [r for r in rows if r.get("status") == "OK"]
-            print(f"# cells: {len(rows)} total, {len(ok)} OK")
-            for r in ok:
-                if r.get("flops_total"):
-                    print(f"roofline_{r['arch']}_{r['shape']},"
-                          f"{r['compute_term_s']*1e6:.1f},"
-                          f"dominant={r['dominant']} "
-                          f"frac={r['roofline_fraction']:.3f}")
-        except Exception as e:                            # noqa: BLE001
-            print(f"# roofline unavailable: {e}")
 
 
 if __name__ == "__main__":

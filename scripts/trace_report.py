@@ -11,8 +11,9 @@ Produces, from the event stream alone (no live engine needed):
   pick);
 * **swap/demote timeline** — every provenance transition in tick order;
 * **tick-latency percentiles** — p50/p90/p99 over ``TickSpan`` durations
-  (tick indices are the timestamps; durations come from the engine's
-  injectable clock);
+  and p50/p90 of each phase (``plan``/``dispatch``/``sync``/``commit``
+  and the caller's share between steps; tick indices are the
+  timestamps, durations come from the engine's injectable clock);
 * **staleness/drift report** — per family: demotions, hot-swaps,
   exhausted-ladder resets, and off-top-rank resolutions — the "is the
   offline ranking still right for this host/traffic?" signal;
@@ -40,12 +41,17 @@ def _percentile(xs: List[float], p: float) -> float:
     return xs[k]
 
 
+#: ``tick_span`` phase fields, in the order a step runs them
+PHASES = ("plan", "dispatch", "sync", "commit", "caller")
+
+
 def aggregate(records: Iterable[Mapping[str, Any]]) -> Dict[str, Any]:
     """Fold an event stream (dicts, as parsed from JSONL) into the report
     structure.  Pure and deterministic: same records, same output."""
     dispatch: Dict[str, Dict[str, Counter]] = {}
     timeline: List[Dict[str, Any]] = []
     durations: List[float] = []
+    phases: Dict[str, List[float]] = {p: [] for p in PHASES}
     ticks = Counter()
     sched = Counter()
     faults = Counter()
@@ -78,6 +84,8 @@ def aggregate(records: Iterable[Mapping[str, Any]]) -> Dict[str, Any]:
                            else rec["source"])})
         elif et == "tick_span":
             durations.append(float(rec["duration_us"]))
+            for p in PHASES:
+                phases[p].append(float(rec[f"{p}_us"]))
             for k in ("admitted", "prefill_tokens", "decode_rows",
                       "preempted", "cancelled", "finished"):
                 ticks[k] += rec[k]
@@ -102,6 +110,9 @@ def aggregate(records: Iterable[Mapping[str, Any]]) -> Dict[str, Any]:
             "p50_us": _percentile(durations, 50),
             "p90_us": _percentile(durations, 90),
             "p99_us": _percentile(durations, 99),
+            "phases": {p: {"p50_us": _percentile(v, 50),
+                           "p90_us": _percentile(v, 90)}
+                       for p, v in phases.items()},
         },
         "sched": {k: int(v) for k, v in sorted(sched.items())},
         "faults": {k: int(v) for k, v in sorted(faults.items())},
@@ -131,6 +142,9 @@ def _render(rep: Dict[str, Any]) -> str:
             f"admitted={t['admitted']} prefill_tokens={t['prefill_tokens']} "
             f"decode_rows={t['decode_rows']} preempted={t['preempted']} "
             f"cancelled={t['cancelled']} finished={t['finished']}")
+        out.append("phases: " + " ".join(
+            f"{p} p50={v['p50_us']:.1f}us p90={v['p90_us']:.1f}us"
+            for p, v in t["phases"].items()))
     if rep["sched"]:
         out.append("sched: " + " ".join(f"{k}={v}" for k, v in
                                         rep["sched"].items()))

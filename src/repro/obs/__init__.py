@@ -18,7 +18,7 @@ one joinable event stream plus one snapshot API:
   tracing is off).
 * :mod:`repro.obs.registry` — :class:`ObsRegistry`: the stats
   dataclasses scattered across pool/scheduler/dispatch/monitor/watchdog
-  unified behind ``snapshot()`` / ``render_text()`` / ``summary_line()``.
+  unified behind ``snapshot()`` / ``summary_line()``.
 
 Everything here is stdlib-only so the light modules (``runtime.faults``,
 ``artifacts.dispatch``, ``runtime.kv_pool``) can import it at module

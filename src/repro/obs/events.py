@@ -9,9 +9,9 @@ is by class *name*, the fields by ``dataclasses.fields``.
 Determinism contract: every field value is an int, float, str, bool, or
 a (possibly nested) tuple of those — ``json.dumps(sort_keys=True)`` over
 them is byte-stable across runs.  Timestamps are tick indices;
-``TickSpan.duration_us`` is the only wall-clock-derived field and it
-comes from the engine's *injectable* clock, so CI runs under a counting
-clock are byte-identical end to end.
+``TickSpan``'s ``*_us`` fields are the only wall-clock-derived fields and
+they come from the engine's *injectable* clock, so CI runs under a
+counting clock are byte-identical end to end.
 """
 from __future__ import annotations
 
@@ -33,7 +33,14 @@ EVENT_TYPES: Dict[str, str] = {
 @dataclass(frozen=True)
 class TickSpan:
     """One engine tick's shape: what the plan scheduled, what committed,
-    how long the host-side step took (on the engine's injectable clock)."""
+    how long the host-side step took and its phases (microseconds on the
+    engine's injectable clock).  ``plan_us`` is the scheduler's tick,
+    ``dispatch_us`` the enqueue of the tick's device work, ``sync_us`` the
+    host blocked materializing sampled tokens at the commit barrier,
+    ``commit_us`` the rest of the commit (outputs appended, retirement);
+    the four add up to ``duration_us`` less an injected hang.
+    ``caller_us`` is the time since the previous step returned: the
+    caller's share, outside ``duration_us`` (0 on the first timed step)."""
 
     tick: int
     admitted: int
@@ -43,6 +50,11 @@ class TickSpan:
     cancelled: int
     finished: int                 # requests that completed this step
     duration_us: float
+    plan_us: float
+    dispatch_us: float
+    sync_us: float
+    commit_us: float
+    caller_us: float
 
 
 @dataclass(frozen=True)
@@ -110,6 +122,9 @@ EVENT_SCHEMA: Dict[str, Dict[str, Tuple[type, ...]]] = {
         "tick": (int,), "admitted": (int,), "prefill_tokens": (int,),
         "decode_rows": (int,), "preempted": (int,), "cancelled": (int,),
         "finished": (int,), "duration_us": (int, float),
+        "plan_us": (int, float), "dispatch_us": (int, float),
+        "sync_us": (int, float), "commit_us": (int, float),
+        "caller_us": (int, float),
     },
     "dispatch_decision": {
         "tick": (int,), "family": (str,), "machine": (str,),

@@ -16,7 +16,7 @@ tracing (PR 4 perf contract): ``sample_frozen_every=N`` opts into a
 with ``surface="warm_sampled"``.
 
 Export is byte-deterministic: records carry tick indices (never wall
-clock — ``TickSpan.duration_us`` comes from the engine's injectable
+clock — ``TickSpan``'s durations come from the engine's injectable
 clock), sequence ids are assigned in emission order, and JSONL encoding
 is ``sort_keys=True, separators=(",", ":")`` — same seed + same schedule
 means byte-identical output (``scripts/ci_obs.py`` gates this).

@@ -5,8 +5,6 @@
 :class:`ObsRegistry` joins them behind one snapshot API:
 
 * :meth:`ObsRegistry.snapshot` — nested plain dict (JSON-ready);
-* :meth:`ObsRegistry.render_text` — Prometheus-style text exposition
-  (``repro_<group>_<name> <value>`` lines, sorted);
 * :meth:`ObsRegistry.summary_line` — the one-line operator summary that
   replaces the scattered prints in ``launch/serve.py``;
 * :meth:`ObsRegistry.kernel_report` — per-kernel provenance lines read
@@ -128,19 +126,6 @@ class ObsRegistry:
         return out
 
     # -- renderings -----------------------------------------------------------
-    def render_text(self) -> str:
-        """Prometheus-style exposition: one ``repro_<group>_<name> <value>``
-        line per numeric counter/gauge, sorted for stable diffs."""
-        lines: List[str] = []
-        for group, section in sorted(self.snapshot().items()):
-            for name, value in sorted(section.items()):
-                if isinstance(value, bool) or not isinstance(value,
-                                                             (int, float)):
-                    continue
-                v = f"{value:.6g}" if isinstance(value, float) else str(value)
-                lines.append(f"repro_{group}_{name} {v}")
-        return "\n".join(lines) + ("\n" if lines else "")
-
     def summary_line(self) -> str:
         """The operator one-liner: each attached surface's headline
         counters, ``|``-separated (the unified replacement for the

@@ -107,6 +107,12 @@ class Request:
     #: structured failure when the request ended abnormally (shed,
     #: cancelled, rejected); ``done`` is True whenever this is set.
     error: Optional[RequestError] = None
+    #: seconds on the scheduler's clock: queued (``Scheduler.submit``),
+    #: first admitted (a re-admission after preemption keeps it), and
+    #: first output token committed (``ServeEngine._commit``)
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 @dataclass
@@ -243,6 +249,7 @@ class Scheduler:
                 f"{self.pool.blocks_for(total)} blocks, pool capacity is "
                 f"{self.pool.capacity}",
                 rid=req.rid)
+        req.t_submit = self.clock()
         if self.max_queue is not None and len(self.queue) >= self.max_queue:
             err = RequestError(
                 "queue_full",
@@ -371,6 +378,8 @@ class Scheduler:
                 seq.chain_hash = chash
                 seq.registered = matched // self.pool.page_size
             self.slots[slot] = seq
+            if req.t_admit is None:
+                req.t_admit = self.clock()
             plan.admitted.append(seq)
             self.stats.admissions += 1
             self._emit("admit", req.rid, slot)

@@ -56,6 +56,7 @@ tables (``strict_plans`` escalates the staleness warning to a refusal).
 from __future__ import annotations
 
 import collections
+import contextlib
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -78,6 +79,15 @@ from .scheduler import Request, Scheduler, SeqState, TickPlan
 from .steps import greedy_sample
 
 PyTree = Any
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(traced: bool, name: str):
+    """A ``jax.profiler.TraceAnnotation`` on the host plane while a
+    recorder is installed; otherwise one shared no-op context, so a step
+    with tracing off builds no span object."""
+    return jax.profiler.TraceAnnotation(name) if traced else _NO_SPAN
 
 
 def warm_kernel_dispatch(cfg: ModelConfig, *,
@@ -205,6 +215,7 @@ class _InFlight:
     prefill_seed: Optional[Tuple[SeqState, jax.Array]] = None  # (seq, (1,1))
     decode_toks: Optional[jax.Array] = None                    # (B, 1)
     decode_seqs: List[SeqState] = field(default_factory=list)
+    sync_s: float = 0.0          # host seconds blocked materializing them
 
 
 class ServeEngine:
@@ -313,6 +324,7 @@ class ServeEngine:
         self.last_tok = jnp.zeros((max_batch, 1), jnp.int32)
         self._inflight: Deque[_InFlight] = collections.deque()
         self._rid = 0
+        self._returned: Optional[float] = None  # clock when step() returned
 
     # -- client API -----------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new: int = 16,
@@ -361,29 +373,47 @@ class ServeEngine:
         ``async_depth=1`` the dispatched tick commits immediately
         (synchronous engine); at depth ``d`` the newest ``d − 1`` ticks
         stay in flight across the return, overlapping host planning with
-        device execution."""
+        device execution.
+
+        Where the watchdog or a recorder needs the tick's duration, the
+        phase boundaries are read on the same injectable clock; with a
+        recorder installed they become a ``TickSpan`` and the step is
+        mirrored into ``serve.*`` profiler spans on the host plane."""
         faults.set_tick(self.sched.ticks)    # arm the drill's tick cursor
         obs.set_tick(self.sched.ticks)       # ...and the trace's, in lockstep
         orec = obs.get_recorder()
-        timed = self.watchdog is not None or orec is not None
+        traced = orec is not None
+        timed = self.watchdog is not None or traced
         t0 = self.clock() if timed else 0.0
-        done: List[Request] = []
-        if self._rejected:                   # shed submits surface as done
-            done.extend(self._rejected)
-            self._rejected.clear()
-        if self.monitor is not None:
-            # adaptive loop: cheap counter sampling + (rarely) a hot-swap
-            # through the cache's atomic publish; one modulo check on
-            # non-probe ticks
-            self.monitor.on_tick(self.sched.ticks)
-        tick = self.sched.ticks
-        plan = self.sched.tick()
-        done.extend(plan.cancelled)          # deadline-expired: partial out
-        self._dispatch(plan)
-        while len(self._inflight) > self.async_depth - 1:
-            done.extend(self._commit(self._inflight.popleft()))
+        with _span(traced, "serve.step"):
+            done: List[Request] = []
+            if self._rejected:               # shed submits surface as done
+                done.extend(self._rejected)
+                self._rejected.clear()
+            tick = self.sched.ticks
+            with _span(traced, "serve.plan"):
+                if self.monitor is not None:
+                    # adaptive loop: cheap counter sampling + (rarely) a
+                    # hot-swap through the cache's atomic publish; one
+                    # modulo check on non-probe ticks
+                    self.monitor.on_tick(self.sched.ticks)
+                plan = self.sched.tick()
+            t1 = self.clock() if timed else 0.0
+            done.extend(plan.cancelled)      # deadline-expired: partial out
+            with _span(traced, "serve.dispatch"):
+                self._dispatch(plan, traced)
+            t2 = self.clock() if timed else 0.0
+            sync = 0.0
+            while len(self._inflight) > self.async_depth - 1:
+                rec = self._inflight.popleft()
+                done.extend(self._commit(rec, timed, traced))
+                sync += rec.sync_s
         if timed:
-            dt = self.clock() - t0
+            t3 = self.clock()
+            caller = t0 - self._returned if self._returned is not None \
+                else 0.0
+            self._returned = t3
+            dt = t3 - t0
             spec = faults.maybe_fault("serve.tick")
             if spec is not None and spec.kind == "slow":
                 dt += spec.arg / 1e6         # injected hang, in microseconds
@@ -391,9 +421,10 @@ class ServeEngine:
                 self.watchdog.observe(dt, tick)
             if orec is not None:
                 # one span per tick: what the plan scheduled, what
-                # committed, and the host-side duration on the engine's
-                # injectable clock (tick indices are the only timestamps,
-                # so a counting clock makes the whole trace deterministic)
+                # committed, and the host-side duration and its phases on
+                # the engine's injectable clock (tick indices are the only
+                # timestamps, so a counting clock makes the whole trace
+                # deterministic)
                 orec.emit(TickSpan(
                     tick=tick, admitted=len(plan.admitted),
                     prefill_tokens=(plan.prefill[2]
@@ -401,7 +432,12 @@ class ServeEngine:
                     decode_rows=len(plan.decode),
                     preempted=len(plan.preempted),
                     cancelled=len(plan.cancelled), finished=len(done),
-                    duration_us=dt * 1e6))
+                    duration_us=dt * 1e6, plan_us=(t1 - t0) * 1e6,
+                    dispatch_us=(t2 - t1) * 1e6, sync_us=sync * 1e6,
+                    commit_us=(t3 - t2 - sync) * 1e6,
+                    caller_us=caller * 1e6))
+        else:
+            self._returned = None
         return done
 
     def _guard(self, site: str, seqs: Tuple[SeqState, ...], fn, *args):
@@ -449,7 +485,7 @@ class ServeEngine:
         self._cache.demote(fam, mach, data, error=error,
                            tick=self.sched.ticks)
 
-    def _dispatch(self, plan: TickPlan) -> None:
+    def _dispatch(self, plan: TickPlan, traced: bool = False) -> None:
         """Execute one tick plan: enqueue the CoW copies, at most one
         prefill chunk, and the batched decode; record the device handles
         of the sampled tokens as an in-flight tick.  No host sync here —
@@ -462,7 +498,9 @@ class ServeEngine:
         ``dead`` re-checks).  The in-flight record is appended even when a
         fatal fault aborts the tick midway: whatever was dispatched before
         the abort must still reach the commit barrier, or the pipeline's
-        position accounting wedges and the engine can never drain."""
+        position accounting wedges and the engine can never drain.
+        ``traced`` wraps each enqueue in a ``serve.cow`` /
+        ``serve.prefill`` / ``serve.decode`` profiler span."""
         for seq in plan.admitted:
             self._reset_slot(seq.slot)
         rec = _InFlight()
@@ -470,18 +508,21 @@ class ServeEngine:
             for (src, dst), owner in zip(plan.cow, plan.cow_owners):
                 # duplicate shared blocks BEFORE this tick writes into
                 # them; other owners keep reading the original
-                out = self._guard("serve.cow", (owner,), self._copy,
-                                  self.cache, jnp.int32(src), jnp.int32(dst))
+                with _span(traced, "serve.cow"):
+                    out = self._guard("serve.cow", (owner,), self._copy,
+                                      self.cache, jnp.int32(src),
+                                      jnp.int32(dst))
                 if out is not None:
                     self.cache = out
             if plan.prefill is not None and not plan.prefill[0].dead:
                 seq, start, chunk = plan.prefill
                 toks = jnp.asarray(seq.target[None, start:start + chunk])
-                out = self._guard("serve.prefill", (seq,), self._prefill,
-                                  self.params, toks, self.cache,
-                                  jnp.int32(start),
-                                  jnp.asarray(self._block_table(seq)[None]),
-                                  jnp.int32(seq.slot))
+                with _span(traced, "serve.prefill"):
+                    out = self._guard(
+                        "serve.prefill", (seq,), self._prefill, self.params,
+                        toks, self.cache, jnp.int32(start),
+                        jnp.asarray(self._block_table(seq)[None]),
+                        jnp.int32(seq.slot))
                 if out is not None:
                     seed, self.cache = out
                     self.sched.note_prefill(seq, chunk)
@@ -503,10 +544,12 @@ class ServeEngine:
                 # one decode for the whole pool with per-row block tables
                 # (continuous batching); non-decoding rows write the garbage
                 # block and keep their SSM state via the mask.
-                out = self._guard("serve.decode", tuple(decoding),
-                                  self._decode, self.params, self.last_tok,
-                                  self.cache, jnp.asarray(idx),
-                                  jnp.asarray(bts), jnp.asarray(mask))
+                with _span(traced, "serve.decode"):
+                    out = self._guard("serve.decode", tuple(decoding),
+                                      self._decode, self.params,
+                                      self.last_tok, self.cache,
+                                      jnp.asarray(idx), jnp.asarray(bts),
+                                      jnp.asarray(mask))
                 if out is not None:
                     toks, self.last_tok, self.cache = out
                     for seq in decoding:
@@ -520,24 +563,39 @@ class ServeEngine:
             raise
         self._inflight.append(rec)
 
-    def _commit(self, rec: _InFlight) -> List[Request]:
+    def _commit(self, rec: _InFlight, timed: bool = False,
+                traced: bool = False) -> List[Request]:
         """Commit barrier: materialize one finished tick's sampled tokens
         (the pipeline's only host sync), append them to request outputs —
         skipping sequences preempted (dead: greedy recompute regenerates
         their tokens) or already finished (EOS found by an earlier commit:
         later speculative tokens are discarded) — then reconcile EOS /
-        ``max_new`` and retire."""
-        if rec.prefill_seed is not None:
-            seq, seed = rec.prefill_seed
-            if not seq.dead and not seq.req.done:
-                seq.req.out.append(int(np.asarray(seed)[0, 0]))
-        if rec.decode_seqs:
-            nxt = np.asarray(rec.decode_toks)
-            for seq in rec.decode_seqs:
-                if seq.dead or seq.req.done:
+        ``max_new`` and retire.  A request's first committed token stamps
+        its ``t_first``.  ``timed`` records the host's wait for the device
+        in ``rec.sync_s``; ``traced`` wraps the wait in ``serve.sync`` and
+        the rest in ``serve.commit``."""
+        t = self.clock() if timed else 0.0
+        with _span(traced, "serve.sync"):
+            seed = (None if rec.prefill_seed is None
+                    else int(np.asarray(rec.prefill_seed[1])[0, 0]))
+            nxt = np.asarray(rec.decode_toks) if rec.decode_seqs else None
+        if timed:
+            rec.sync_s = self.clock() - t
+        with _span(traced, "serve.commit"):
+            now: Optional[float] = None
+            appends = ([(rec.prefill_seed[0], seed)] if seed is not None
+                       else [])
+            appends += [(seq, int(nxt[seq.slot, 0]))
+                        for seq in rec.decode_seqs]
+            for seq, tok in appends:
+                req = seq.req
+                if seq.dead or req.done:
                     continue
-                seq.req.out.append(int(nxt[seq.slot, 0]))
-        return self._retire()
+                if req.t_first is None:
+                    now = self.clock() if now is None else now
+                    req.t_first = now
+                req.out.append(tok)
+            return self._retire()
 
     def _retire(self) -> List[Request]:
         done = []
@@ -562,7 +620,7 @@ class ServeEngine:
     def registry(self) -> ObsRegistry:
         """This engine's unified metrics registry: pool, scheduler,
         dispatch cache, monitor, and watchdog behind one ``snapshot()`` /
-        ``render_text()`` / ``summary_line()`` surface.  Parts are
+        ``summary_line()`` surface.  Parts are
         resolved per snapshot, so a monitor attached later is reported."""
         return ObsRegistry.from_engine(self)
 

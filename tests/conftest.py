@@ -63,6 +63,25 @@ def fake_clock():
     return FakeClock()
 
 
+class CountingClock(FakeClock):
+    """A clock that advances ``step`` seconds on every read, so the time
+    the engine measures is a count of its own clock reads: independent of
+    how busy the host is, and the same on every run."""
+
+    def __init__(self, step: float = 1e-4):
+        super().__init__()
+        self.step = step
+
+    def __call__(self) -> float:
+        self.now += self.step
+        return self.now
+
+
+@pytest.fixture
+def counting_clock():
+    return CountingClock()
+
+
 class SkewedTimer:
     """A deterministic ``repro.tuning.measure.Timer`` whose measurements
     are dictated per candidate — the drift-injection harness.

@@ -23,7 +23,8 @@ Layers drilled:
   validation, the tick watchdog, and monitor probe failures.
 
 Determinism: every schedule is seeded; no test depends on wall-clock time
-(deadline tests inject ``FakeClock``)."""
+(deadline tests inject ``FakeClock``, the watchdog test a
+``CountingClock``)."""
 import json
 
 import numpy as np
@@ -568,9 +569,11 @@ def test_engine_deadline_and_shed_surface_as_done(smoke_model, fake_clock):
 
 
 @pytest.mark.slow
-def test_watchdog_flags_injected_slow_tick(smoke_model):
+def test_watchdog_flags_injected_slow_tick(smoke_model, counting_clock):
     cfg, params = smoke_model
-    eng = _build_engine(cfg, params)
+    # tick durations count clock reads, so a real host stall (a loaded CI
+    # machine) cannot flag some other tick
+    eng = _build_engine(cfg, params, clock=counting_clock)
     eng.submit(np.arange(2, 10), max_new=24)
     # a 10-second hang injected at tick 16, after the median settles
     with faults.inject([FaultSpec("serve.tick", 16, "slow",

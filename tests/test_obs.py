@@ -18,7 +18,13 @@ Acceptance properties:
 - ``DispatchCache`` emits a provenance record per non-frozen resolution
   (tier source, candidate rank, demotion marks) and ``demote`` lands in
   the trace;
-- ``ObsRegistry`` snapshots every surface and renders stable text.
+- ``ObsRegistry`` snapshots every surface and renders the summary line;
+- each ``TickSpan`` splits its step into plan / dispatch / sync / commit
+  phases that add up to its duration, plus the caller's time between
+  steps; each request carries submit / admit / first-token stamps; while
+  a recorder is installed the step is mirrored into nested ``serve.*``
+  profiler spans, and with none no span object is built; the jitted
+  steps keep the module names trace reductions key on.
 """
 import dataclasses
 import json
@@ -44,6 +50,13 @@ MM_DATA = {"M": 64, "N": 64, "K": 64}
 def _adm(i):
     return AdmissionDecision(tick=i, action="admit", rid=i, slot=0,
                              queue_depth=0)
+
+
+def _span(tick):
+    return TickSpan(tick=tick, admitted=1, prefill_tokens=8, decode_rows=2,
+                    preempted=0, cancelled=0, finished=1, duration_us=12.5,
+                    plan_us=2.0, dispatch_us=3.0, sync_us=6.0,
+                    commit_us=1.5, caller_us=4.0)
 
 
 # ---------------------------------------------------------------------------
@@ -91,9 +104,7 @@ def test_export_jsonl_is_byte_deterministic():
             tick=3, family="matmul", machine="tpu_v5e", data=(("M", 8),),
             bucket="b0", leaf=2, assignment=(("TX", 4),), source="measured",
             surface="resolve", rank=1, demoted=0))
-        rec.emit(TickSpan(tick=3, admitted=1, prefill_tokens=8,
-                          decode_rows=2, preempted=0, cancelled=0,
-                          finished=1, duration_us=12.5))
+        rec.emit(_span(3))
         return rec.export_jsonl()
 
     a, b = build(), build()
@@ -137,6 +148,22 @@ def test_validate_record_rejects_malformed_records():
     for bad in bads:
         with pytest.raises(ValueError):
             validate_record(bad)
+
+
+PHASE_FIELDS = ("plan_us", "dispatch_us", "sync_us", "commit_us",
+                "caller_us")
+
+
+@pytest.mark.parametrize("field", PHASE_FIELDS)
+def test_tick_span_phase_fields_are_required(field):
+    rec = FlightRecorder()
+    rec.emit(_span(0))
+    good = rec.records()[0]
+    validate_record(good)                    # the new fields are accepted
+    with pytest.raises(ValueError, match=field):
+        validate_record({k: v for k, v in good.items() if k != field})
+    with pytest.raises(ValueError):
+        validate_record({**good, field: "fast"})
 
 
 # ---------------------------------------------------------------------------
@@ -296,7 +323,7 @@ def test_demote_lands_in_trace_with_provenance():
 
 
 # ---------------------------------------------------------------------------
-# Registry: snapshot / render_text / summary_line
+# Registry: snapshot / summary_line
 # ---------------------------------------------------------------------------
 
 def test_registry_snapshot_render_and_summary():
@@ -312,14 +339,179 @@ def test_registry_snapshot_render_and_summary():
     assert snap["recorder"] == {"emitted": 1, "buffered": 1, "dropped": 0,
                                 "capacity": 16, "sample_frozen_every": 0}
     assert snap["monitor"] == {} and snap["watchdog"] == {}
-    lines = reg.render_text().splitlines()
-    assert "repro_pool_capacity 8" in lines
-    assert "repro_recorder_emitted 1" in lines
-    assert lines == sorted(lines)            # stable exposition order
-    for line in lines:
-        name, value = line.rsplit(" ", 1)
-        assert name.startswith("repro_")
-        float(value)                         # every value parses numeric
     line = reg.summary_line()
     assert line.startswith("obs ")
     assert "ticks=0" in line and "trace n=1" in line
+
+
+# ---------------------------------------------------------------------------
+# The engine tick on the program's clock: phases, request stamps, spans
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def smoke_model():
+    import jax
+    from repro.configs import get_smoke_config
+    from repro.models import init_model
+    cfg = get_smoke_config("yi_6b")
+    params, _ = init_model(jax.random.PRNGKey(0), cfg)
+    return cfg, params
+
+
+def _engine(smoke_model, **kw):
+    from repro.runtime import ServeEngine
+    cfg, params = smoke_model
+    kw.setdefault("max_batch", 2)
+    kw.setdefault("max_len", 48)
+    kw.setdefault("page_size", 4)
+    kw.setdefault("prefill_chunk", 8)
+    return ServeEngine(cfg, params, **kw)
+
+
+def _prompts(smoke_model, n, length, seed=5):
+    cfg, _ = smoke_model
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab, length).astype(np.int32)
+            for _ in range(n)]
+
+
+def test_tick_phases_add_up_and_caller_is_time_between_steps(
+        smoke_model, counting_clock):
+    clock = counting_clock
+    eng = _engine(smoke_model, clock=clock)
+    prompts = _prompts(smoke_model, 3, 9)
+    with tracing(capacity=1 << 12) as rec:
+        returned = None
+        expected_caller = []
+        for i in range(40):
+            if i < len(prompts):
+                eng.submit(prompts[i], max_new=6)   # the caller's own work
+            clock.advance(0.25 * (i % 3))
+            # the step's first read lands one clock step after this
+            expected_caller.append(
+                0.0 if returned is None else clock.now + clock.step - returned)
+            eng.step()
+            returned = clock.now
+            if not eng.sched.has_work():
+                break
+    spans = [r for r in rec.records() if r["etype"] == "tick_span"]
+    assert len(spans) == len(expected_caller) > 10
+    for span, caller in zip(spans, expected_caller):
+        validate_record(span)
+        phases = (span["plan_us"] + span["dispatch_us"] + span["sync_us"]
+                  + span["commit_us"])
+        assert min(span[f] for f in PHASE_FIELDS) >= 0.0
+        assert span["plan_us"] > 0 and span["dispatch_us"] > 0
+        # the phases cover the step, up to float rounding
+        assert 0.0 <= span["duration_us"] - phases + 1e-6 <= 1e-3
+        assert span["caller_us"] == pytest.approx(caller * 1e6, abs=1e-3)
+    # a tick that committed tokens waited on them
+    assert all(s["sync_us"] > 0 for s in spans if s["decode_rows"])
+
+
+def test_request_stamps_are_ordered_and_admit_survives_preemption(
+        smoke_model, fake_clock):
+    # the tight pool of test_chunked_prefill's preemption test: the two
+    # rows' joint decode growth overflows it
+    eng = _engine(smoke_model, num_blocks=9, watermark_blocks=0,
+                  clock=fake_clock)
+    for p in _prompts(smoke_model, 3, 9, seed=3):
+        eng.submit(p, max_new=10)
+    reqs = {r.rid: r for r in eng.sched.queue}
+    first_admit, clock_at = {}, {}
+    finished = []
+    with tracing(capacity=1 << 12) as rec:
+        for _ in range(200):
+            fake_clock.advance(0.01)
+            clock_at[eng.sched.ticks] = fake_clock.now
+            finished.extend(eng.step())
+            for rid, r in reqs.items():
+                if r.t_admit is not None:
+                    first_admit.setdefault(rid, r.t_admit)
+            if not eng.sched.has_work():
+                break
+    assert sorted(r.rid for r in finished) == sorted(reqs)
+    for rid, r in reqs.items():
+        assert r.t_submit <= r.t_admit <= r.t_first, rid
+        assert r.t_admit == first_admit[rid]
+    admits = {}
+    for e in rec.records():
+        if e["etype"] == "admission_decision" and e["action"] == "admit":
+            admits.setdefault(e["rid"], []).append(clock_at[e["tick"]])
+    readmitted = [rid for rid, at in admits.items() if len(at) > 1]
+    assert eng.sched.stats.preemptions > 0 and readmitted
+    for rid in readmitted:
+        # re-admitted in a later step, stamped at the first admission
+        assert reqs[rid].t_admit == admits[rid][0] < admits[rid][-1]
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each span
+    built, and its parent when entered."""
+
+    built = []
+    entered = []
+    _open = []
+
+    def __init__(self, name):
+        self.name = name
+        _Annotation.built.append(name)
+
+    def __enter__(self):
+        parent = _Annotation._open[-1] if _Annotation._open else None
+        _Annotation.entered.append((self.name, parent))
+        _Annotation._open.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        _Annotation._open.pop()
+        return False
+
+
+def test_serve_spans_only_with_a_recorder_and_nested(smoke_model,
+                                                     monkeypatch):
+    import jax
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    monkeypatch.setattr(_Annotation, "built", [])
+    monkeypatch.setattr(_Annotation, "entered", [])
+    cfg, _ = smoke_model
+    # a leader, then a follower diverging mid-block: the follower maps the
+    # shared prefix and its first write copies a block (``serve.cow``)
+    rng = np.random.default_rng(1234)
+    lead = rng.integers(0, cfg.vocab, 24).astype(np.int32)
+    follow = np.concatenate([lead[:22], rng.integers(0, cfg.vocab, 6)]
+                            ).astype(np.int32)
+    eng = _engine(smoke_model, max_len=64, prefix_sharing=True)
+    assert get_recorder() is None
+    eng.submit(lead, max_new=4)
+    eng.run_until_drained()
+    assert _Annotation.built == []          # tracing off: nothing built
+    with tracing() as rec:
+        eng.submit(follow, max_new=4)
+        eng.run_until_drained()
+    assert rec.records() and _Annotation._open == []
+    parents = {}
+    for name, parent in _Annotation.entered:
+        parents.setdefault(name, set()).add(parent)
+    assert parents == {
+        "serve.step": {None},
+        "serve.plan": {"serve.step"}, "serve.dispatch": {"serve.step"},
+        "serve.sync": {"serve.step"}, "serve.commit": {"serve.step"},
+        "serve.cow": {"serve.dispatch"}, "serve.prefill": {"serve.dispatch"},
+        "serve.decode": {"serve.dispatch"}}
+    steps = sum(1 for r in rec.records() if r["etype"] == "tick_span")
+    assert Counter(n for n, _ in _Annotation.entered)["serve.step"] == steps
+
+
+def test_jitted_steps_keep_the_names_trace_reductions_key_on(smoke_model):
+    import jax.numpy as jnp
+    eng = _engine(smoke_model)
+    nblk, B = eng.blocks_per_seq, eng.max_batch
+    prefill = eng._prefill.lower(
+        eng.params, jnp.zeros((1, 8), jnp.int32), eng.cache, jnp.int32(0),
+        jnp.zeros((1, nblk), jnp.int32), jnp.int32(0))
+    decode = eng._decode.lower(
+        eng.params, eng.last_tok, eng.cache, jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B, nblk), jnp.int32), jnp.zeros((B,), bool))
+    assert "module @jit_prefill" in prefill.as_text()
+    assert "module @jit_decode" in decode.as_text()
