@@ -152,6 +152,17 @@ def kernel_cases():
                          for k, s in zip(keys, shapes))
         return make
 
+    def paged_args(key):
+        # Qwen1.5-4B's decode attention at the serve phase's settings: 4
+        # rows of ragged lengths over a pool of 4 x 64 scattered pages
+        kq, kk, kv, kl, kt = jax.random.split(key, 5)
+        pool = (20, 4 * 64 + 1, 16, 128)
+        return (jax.random.normal(kq, (4, 20, 128), jnp.bfloat16),
+                jax.random.normal(kk, pool, jnp.bfloat16),
+                jax.random.normal(kv, pool, jnp.bfloat16),
+                jax.random.randint(kl, (4,), 1, 1025),
+                jax.random.permutation(kt, 4 * 64).reshape(4, 64) + 1)
+
     def ssd_args(key):
         x, a, b, c = normals((1024, 24, 64), (1024, 24), (1024, 24, 128),
                              (1024, 24, 128))(key)
@@ -167,6 +178,10 @@ def kernel_cases():
          lambda q, k, v: ops.flash_attention(q, k, v, **kw),
          ref.flash_attention,
          normals(*[(20, 1024, 128)] * 3, dtype=jnp.bfloat16)),
+        ("paged_attention", {"B": 4, "NK": 20, "GROUP": 1, "HD": 128,
+                             "PS": 16, "NBLK": 64},
+         lambda *a: ops.paged_attention(*a, **kw), ref.paged_attention,
+         paged_args),
         # mamba2-130m: seq 1024, 24 heads x 64, state 128
         ("ssd_scan", {"SQ": 1024, "HD": 64, "STATE": 128},
          lambda *a: ops.ssd_scan(*a, **kw), ref.ssd_scan, ssd_args),

@@ -33,14 +33,16 @@ from .flash_attention import FAMILY as FLASH_FAMILY
 from .jacobi1d import FAMILY as JACOBI_FAMILY
 from .matadd import FAMILY as MATADD_FAMILY
 from .matmul import FAMILY as MATMUL_FAMILY
+from .paged_attention import FAMILY as PAGED_FAMILY
 from .ssd_scan import FAMILY as SSD_FAMILY
 from .transpose import FAMILY as TRANSPOSE_FAMILY
 
 FAMILIES = {f.name: f for f in (MATMUL_FAMILY, MATADD_FAMILY, JACOBI_FAMILY,
-                                TRANSPOSE_FAMILY, FLASH_FAMILY, SSD_FAMILY)}
+                                TRANSPOSE_FAMILY, FLASH_FAMILY, SSD_FAMILY,
+                                PAGED_FAMILY)}
 
 
-def _resolve_impl(impl: str) -> str:
+def resolve_impl(impl: str) -> str:
     if impl != "auto":
         return impl
     return "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -66,7 +68,7 @@ def select(family_name: str, data: Mapping[str, int],
 def matmul(a: jax.Array, b: jax.Array, *, impl: str = "auto",
            machine: Optional[MachineDescription] = None,
            interpret: bool = False) -> jax.Array:
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.matmul(a, b)
     M, K = a.shape
@@ -82,7 +84,7 @@ def matmul(a: jax.Array, b: jax.Array, *, impl: str = "auto",
 def matadd(a: jax.Array, b: jax.Array, *, impl: str = "auto",
            machine: Optional[MachineDescription] = None,
            interpret: bool = False) -> jax.Array:
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.matadd(a, b)
     M, N = a.shape
@@ -97,7 +99,7 @@ def matadd(a: jax.Array, b: jax.Array, *, impl: str = "auto",
 def jacobi1d(x: jax.Array, steps: int, *, impl: str = "auto",
              machine: Optional[MachineDescription] = None,
              interpret: bool = False) -> jax.Array:
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.jacobi1d(x, steps)
     (n,) = x.shape
@@ -111,7 +113,7 @@ def jacobi1d(x: jax.Array, steps: int, *, impl: str = "auto",
 def transpose(a: jax.Array, *, impl: str = "auto",
               machine: Optional[MachineDescription] = None,
               interpret: bool = False) -> jax.Array:
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.transpose(a)
     M, N = a.shape
@@ -128,7 +130,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     impl: str = "auto",
                     machine: Optional[MachineDescription] = None,
                     interpret: bool = False) -> jax.Array:
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.flash_attention(q, k, v, causal=causal, window=window)
     h, sq, d = q.shape
@@ -138,13 +140,35 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return fn(q, k, v, causal=causal, window=window)
 
 
+# -- paged attention (decode) ---------------------------------------------------
+
+def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                    lengths: jax.Array, block_tables: jax.Array, *,
+                    impl: str = "auto",
+                    machine: Optional[MachineDescription] = None,
+                    interpret: bool = False) -> jax.Array:
+    """One decode token per row over a head-major page pool: q (B, nh, hd),
+    pools (nk, num_pages, page_size, hd), lengths (B,), block_tables
+    (B, nblk)."""
+    impl = resolve_impl(impl)
+    if impl == "xla":
+        return ref.paged_attention(q, k_pages, v_pages, lengths, block_tables)
+    B, nh, hd = q.shape
+    nk, _, ps, _ = k_pages.shape
+    fn = get_default_cache().warm_callable(
+        PAGED_FAMILY, machine or default_machine(),
+        (("B", B), ("NK", nk), ("GROUP", nh // nk), ("HD", hd), ("PS", ps),
+         ("NBLK", block_tables.shape[1])), interpret)
+    return fn(q, k_pages, v_pages, lengths, block_tables)
+
+
 # -- SSD scan --------------------------------------------------------------------
 
 def ssd_scan(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
              impl: str = "auto",
              machine: Optional[MachineDescription] = None,
              interpret: bool = False) -> jax.Array:
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.ssd_scan(x, a, b, c)
     seq, heads, hd = x.shape

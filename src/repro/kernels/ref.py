@@ -63,6 +63,30 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       ).astype(q.dtype)
 
 
+def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                    lengths: jax.Array, block_tables: jax.Array,
+                    scale: float | None = None) -> jax.Array:
+    """Paged decode attention by gathering every row's whole block table.
+
+    q: (B, nh, hd); k_pages, v_pages: (nk, num_pages, page_size, hd);
+    lengths: (B,); block_tables: (B, nblk).  Row ``b`` attends to its
+    logical positions ``0..lengths[b]-1``; GQA by head grouping, f32
+    softmax."""
+    B, nh, hd = q.shape
+    nk, _, ps, _ = k_pages.shape
+    nblk = block_tables.shape[1]
+    scale = scale if scale is not None else 1.0 / (hd ** 0.5)
+    k = k_pages[:, block_tables].reshape(nk, B, nblk * ps, hd)
+    v = v_pages[:, block_tables].reshape(nk, B, nblk * ps, hd)
+    qf = q.astype(jnp.float32).reshape(B, nk, nh // nk, hd)
+    logits = jnp.einsum("bhgd,hbkd->bhgk", qf, k.astype(jnp.float32)) * scale
+    live = jnp.arange(nblk * ps)[None, :] < lengths[:, None]    # (B, Sk)
+    logits = jnp.where(live[:, None, None], logits, -jnp.inf)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhgk,hbkd->bhgd", probs, v.astype(jnp.float32))
+    return out.reshape(B, nh, hd).astype(q.dtype)
+
+
 def ssd_scan(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array
              ) -> jax.Array:
     """Mamba-2 SSD (state-space dual) sequential oracle.

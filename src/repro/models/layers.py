@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
+from ..kernels import ops
 from ..kernels.ssd_scan import ssd_chunk
 
 Params = Dict[str, Any]
@@ -180,6 +181,18 @@ def _sdpa(q, k, v, *, causal: bool, window: Optional[int],
     return out.reshape(B, Sq, nh, hd).astype(q.dtype)
 
 
+def paged_kernel_serves(cfg: ModelConfig, *, decode: bool,
+                        pool_dtype) -> bool:
+    """Whether a paged attention call reads the pool through the
+    ``paged_attention`` kernel: a decode step (one token per row, per-row
+    index) over a bf16 pool, in a layer with no sliding window, on a
+    backend where ``"auto"`` resolves to Pallas (a TPU).  Prefill chunks,
+    windowed layers, float32 pools and the CPU gather instead."""
+    return (decode and cfg.window is None
+            and jnp.dtype(pool_dtype) == jnp.bfloat16
+            and ops.resolve_impl("auto") == "pallas")
+
+
 def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
               positions: jax.Array,
               cache: Optional[Dict[str, jax.Array]] = None,
@@ -199,11 +212,14 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
     return_kv: return the projected (k, v) instead of a cache dict (the
     whisper prefill writes them into the cross cache).
     block_tables: (B, nblk) int32 — *paged* KV cache.  The cache leaves are
-    then block pools of shape (num_blocks, page_size, nk, hd) shared by
-    every sequence, and row ``b``'s logical block ``j`` lives in physical
-    block ``block_tables[b, j]``.  Unallocated entries may point anywhere
-    (conventionally the engine's garbage block 0): their logical positions
-    lie beyond the row's ``cache_index`` and are causally masked.
+    then head-major block pools of shape (nk, num_blocks, page_size, hd)
+    shared by every sequence, and row ``b``'s logical block ``j`` lives in
+    physical block ``block_tables[b, j]``.  Unallocated entries may point
+    anywhere (conventionally the engine's garbage block 0): their logical
+    positions lie beyond the row's ``cache_index`` and are never read.  A
+    decode step that :func:`paged_kernel_serves` attends through the
+    ``paged_attention`` kernel, which reads only each row's live pages;
+    every other paged call gathers the rows' whole tables.
     """
     B, Sq, d = x.shape
     nh, nk, hd = cfg.heads, cfg.kv_heads, cfg.hd
@@ -233,26 +249,39 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
         pass                                          # cross-attn: no rope
 
     new_cache = None
+    out = None
     if cache is not None and block_tables is not None:
         # Paged KV pool (serving): scatter this call's K/V into the rows'
-        # physical blocks, then gather each row's logical view for the
-        # attention read.  Works for both the per-row decode step
-        # (cache_index (B,), Sq == 1) and the batch-1 chunked-prefill step
-        # (scalar cache_index, Sq == chunk).  Window semantics come from
-        # the sdpa mask, not a ring buffer — the pool is position-exact.
-        ps = cache["k"].shape[1]
+        # physical blocks, then read them back.  Works for both the per-row
+        # decode step (cache_index (B,), Sq == 1) and the batch-1
+        # chunked-prefill step (scalar cache_index, Sq == chunk).  Window
+        # semantics come from the sdpa mask, not a ring buffer — the pool
+        # is position-exact.
+        ps = cache["k"].shape[2]
         idxv = (cache_index if jnp.ndim(cache_index) == 1
                 else jnp.broadcast_to(cache_index, (B,)))
         ptok = idxv[:, None] + jnp.arange(Sq)[None]          # (B,Sq) logical
         phys = jnp.take_along_axis(block_tables, ptok // ps, axis=1)
         pslot = ptok % ps
-        ck = cache["k"].at[phys, pslot].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[phys, pslot].set(v.astype(cache["v"].dtype))
+        ck = cache["k"].at[:, phys, pslot].set(
+            k.transpose(2, 0, 1, 3).astype(cache["k"].dtype))
+        cv = cache["v"].at[:, phys, pslot].set(
+            v.transpose(2, 0, 1, 3).astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
-        nblk = block_tables.shape[1]
-        k_att = ck[block_tables].reshape(B, nblk * ps, nk, hd).astype(x.dtype)
-        v_att = cv[block_tables].reshape(B, nblk * ps, nk, hd).astype(x.dtype)
-        k_positions = jnp.arange(nblk * ps)
+        decode = Sq == 1 and jnp.ndim(cache_index) == 1
+        if paged_kernel_serves(cfg, decode=decode, pool_dtype=ck.dtype):
+            out = ops.paged_attention(q[:, 0], ck, cv, idxv + 1,
+                                      block_tables)
+        else:
+            # gather each row's whole logical view for the sdpa read
+            nblk = block_tables.shape[1]
+
+            def view(pool):
+                return (pool[:, block_tables].transpose(1, 2, 3, 0, 4)
+                        .reshape(B, nblk * ps, nk, hd).astype(x.dtype))
+
+            k_att, v_att = view(ck), view(cv)
+            k_positions = jnp.arange(nblk * ps)
     elif cache is not None:
         k_len = cache["k"].shape[1]
         ring = cfg.window is not None and k_len <= cfg.window
@@ -319,17 +348,19 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
                        if context is None and precomputed_kv is None
                        else jnp.arange(k.shape[1]))
     cross = context is not None or precomputed_kv is not None
-    if cache is not None and "kv_cache_hd" in cfg.perf_flags:
-        # the cache is head_dim-sharded; matching q makes GSPMD compute the
-        # QK contraction distributed (partial scores + ~65MB all-reduce)
-        # instead of all-gathering the ~1GB K cache per layer (§Perf C2)
-        from ..distributed import sharding as dist
-        q = dist.constrain(q, ("batch", None, None, "kv_hd"))
-    out = sdpa_auto(q, k_att, v_att,
-                    causal=causal and not cross,
-                    window=cfg.window if not cross else None,
-                    q_positions=positions, k_positions=k_positions,
-                    flags=cfg.perf_flags)
+    if out is None:
+        if cache is not None and "kv_cache_hd" in cfg.perf_flags:
+            # the cache is head_dim-sharded; matching q makes GSPMD compute
+            # the QK contraction distributed (partial scores + ~65MB
+            # all-reduce) instead of all-gathering the ~1GB K cache per
+            # layer (§Perf C2)
+            from ..distributed import sharding as dist
+            q = dist.constrain(q, ("batch", None, None, "kv_hd"))
+        out = sdpa_auto(q, k_att, v_att,
+                        causal=causal and not cross,
+                        window=cfg.window if not cross else None,
+                        q_positions=positions, k_positions=k_positions,
+                        flags=cfg.perf_flags)
     out = out.reshape(B, Sq, nh * hd)
     proj = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x.dtype))
     if return_kv:

@@ -482,10 +482,10 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, page_size: int,
     Lc = cfg.layers
     c: Dict[str, jax.Array] = {}
     if _has_attn(cfg):
-        c["k"] = jnp.zeros((Lc, num_blocks, page_size, cfg.kv_heads, cfg.hd),
-                           dtype)
-        c["v"] = jnp.zeros((Lc, num_blocks, page_size, cfg.kv_heads, cfg.hd),
-                           dtype)
+        # head-major: one page of one KV head is one (page_size, hd) tile
+        shape = (Lc, cfg.kv_heads, num_blocks, page_size, cfg.hd)
+        c["k"] = jnp.zeros(shape, dtype)
+        c["v"] = jnp.zeros(shape, dtype)
     if _has_ssm(cfg):
         s = cfg.ssm
         c["ssm"] = jnp.zeros((Lc, batch, s.heads, s.state, s.head_dim),
@@ -508,8 +508,8 @@ def paged_copy_block(cache: Dict[str, jax.Array], src: jax.Array,
     out = dict(cache)
     for key in ("k", "v"):
         if key in cache:
-            out[key] = cache[key].at[:, dst].set(
-                jax.lax.dynamic_index_in_dim(cache[key], src, axis=1,
+            out[key] = cache[key].at[:, :, dst].set(
+                jax.lax.dynamic_index_in_dim(cache[key], src, axis=2,
                                              keepdims=False))
     return out
 

@@ -40,7 +40,14 @@ class TickSpan:
     ``commit_us`` the rest of the commit (outputs appended, retirement);
     the four add up to ``duration_us`` less an injected hang.
     ``caller_us`` is the time since the previous step returned: the
-    caller's share, outside ``duration_us`` (0 on the first timed step)."""
+    caller's share, outside ``duration_us`` (0 on the first timed step).
+    ``decode_kernel`` says whether the engine's decode program attends
+    through the ``paged_attention`` kernel (decided when the step is
+    built); ``kv_pages_read`` is the KV pages its decode rows own this
+    tick, the sum of ``ceil((pos + 1) / page_size)``, which is what the
+    kernel reads, and ``kv_pages_table`` the ``max_batch x
+    blocks_per_seq`` pages the gather path reads (both 0 without a
+    decode)."""
 
     tick: int
     admitted: int
@@ -55,6 +62,9 @@ class TickSpan:
     sync_us: float
     commit_us: float
     caller_us: float
+    decode_kernel: bool = False
+    kv_pages_read: int = 0
+    kv_pages_table: int = 0
 
 
 @dataclass(frozen=True)
@@ -124,7 +134,8 @@ EVENT_SCHEMA: Dict[str, Dict[str, Tuple[type, ...]]] = {
         "finished": (int,), "duration_us": (int, float),
         "plan_us": (int, float), "dispatch_us": (int, float),
         "sync_us": (int, float), "commit_us": (int, float),
-        "caller_us": (int, float),
+        "caller_us": (int, float), "decode_kernel": (bool,),
+        "kv_pages_read": (int,), "kv_pages_table": (int,),
     },
     "dispatch_decision": {
         "tick": (int,), "family": (str,), "machine": (str,),

@@ -67,6 +67,7 @@ import numpy as np
 from ..core.params import MachineDescription, default_machine
 from ..models import (init_paged_cache, paged_copy_block, paged_decode_step,
                       paged_prefill_chunk)
+from ..models.layers import paged_kernel_serves
 from ..models.config import ModelConfig
 from ..obs import ObsRegistry
 from ..obs import recorder as obs
@@ -321,6 +322,9 @@ class ServeEngine:
         self._decode = jax.jit(decode, donate_argnums=(2,))
         self._copy = jax.jit(paged_copy_block, donate_argnums=(0,))
         self.cache = init_paged_cache(cfg, num_blocks, page_size, max_batch)
+        # the decode program's attention read, as its layers route it
+        self.decode_kernel = "k" in self.cache and paged_kernel_serves(
+            cfg, decode=True, pool_dtype=self.cache["k"].dtype)
         self.last_tok = jnp.zeros((max_batch, 1), jnp.int32)
         self._inflight: Deque[_InFlight] = collections.deque()
         self._rid = 0
@@ -401,7 +405,7 @@ class ServeEngine:
             t1 = self.clock() if timed else 0.0
             done.extend(plan.cancelled)      # deadline-expired: partial out
             with _span(traced, "serve.dispatch"):
-                self._dispatch(plan, traced)
+                pages = self._dispatch(plan, traced)
             t2 = self.clock() if timed else 0.0
             sync = 0.0
             while len(self._inflight) > self.async_depth - 1:
@@ -435,7 +439,10 @@ class ServeEngine:
                     duration_us=dt * 1e6, plan_us=(t1 - t0) * 1e6,
                     dispatch_us=(t2 - t1) * 1e6, sync_us=sync * 1e6,
                     commit_us=(t3 - t2 - sync) * 1e6,
-                    caller_us=caller * 1e6))
+                    caller_us=caller * 1e6,
+                    decode_kernel=self.decode_kernel, kv_pages_read=pages,
+                    kv_pages_table=(self.max_batch * self.blocks_per_seq
+                                    if pages else 0)))
         else:
             self._returned = None
         return done
@@ -485,12 +492,13 @@ class ServeEngine:
         self._cache.demote(fam, mach, data, error=error,
                            tick=self.sched.ticks)
 
-    def _dispatch(self, plan: TickPlan, traced: bool = False) -> None:
+    def _dispatch(self, plan: TickPlan, traced: bool = False) -> int:
         """Execute one tick plan: enqueue the CoW copies, at most one
         prefill chunk, and the batched decode; record the device handles
         of the sampled tokens as an in-flight tick.  No host sync here —
         position accounting advances speculatively (note_prefill /
-        note_decode), outputs land at commit.
+        note_decode), outputs land at commit.  Returns the KV pages the
+        dispatched decode's rows own (0 without a decode).
 
         Every device stage runs under :meth:`_guard`; a stage that fails
         twice poisons its sequences and is skipped (a poisoned sequence is
@@ -504,6 +512,7 @@ class ServeEngine:
         for seq in plan.admitted:
             self._reset_slot(seq.slot)
         rec = _InFlight()
+        pages = 0
         try:
             for (src, dst), owner in zip(plan.cow, plan.cow_owners):
                 # duplicate shared blocks BEFORE this tick writes into
@@ -552,6 +561,8 @@ class ServeEngine:
                                       jnp.asarray(mask))
                 if out is not None:
                     toks, self.last_tok, self.cache = out
+                    pages = sum(-(-(seq.pos + 1) // self.page_size)
+                                for seq in decoding)
                     for seq in decoding:
                         self.sched.note_decode(seq)
                     rec.decode_toks = toks
@@ -562,6 +573,7 @@ class ServeEngine:
             self._inflight.append(rec)
             raise
         self._inflight.append(rec)
+        return pages
 
     def _commit(self, rec: _InFlight, timed: bool = False,
                 traced: bool = False) -> List[Request]:

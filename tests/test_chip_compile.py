@@ -3,7 +3,8 @@ compiler refuses (tiling, scoped VMEM, HBM) fails here, with no chip.
 
 Covers every Pallas family's pick at the shapes ``chip_smoke.py`` runs
 (the head-major ``ssd_scan`` among them) and Qwen1.5-4B's jitted paged
-decode step at published widths, which must fit the chip's 16 GB.  The
+decode step at published widths, which must fit the chip's 16 GB and read
+the pool through the ``paged_attention`` kernel, as it does on a TPU.  The
 topology is described only inside the module fixture below, never while
 a module is imported: only one process may load the TPU compiler's
 library, and the fixture skips where it cannot be loaded.
@@ -18,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.kernels import ops
 from repro.kernels.ops import FAMILIES
 from repro.models import init_model, init_paged_cache
 from repro.runtime.serving import engine_steps
@@ -67,9 +69,13 @@ def test_kernel_pick_compiles_for_v5e(one_chip, family):
     assert "tpu_custom_call" in hlo            # the Pallas kernel is there
 
 
-def test_qwen_paged_decode_step_fits_one_v5e(one_chip):
+def test_qwen_paged_decode_step_fits_one_v5e(one_chip, monkeypatch):
     """The engine's decode step at the smoke's settings: bf16 weights,
-    max_batch 4, max_len 1024, page size 16."""
+    max_batch 4, max_len 1024, page size 16.  The CPU backend this
+    compiles from would route the attention read to the gather path; on
+    the TPU it goes to the kernel, so the test resolves ``impl`` as a TPU
+    process does."""
+    monkeypatch.setattr(ops, "resolve_impl", lambda impl: "pallas")
     cfg = dataclasses.replace(get_config("qwen1.5-4b"),
                               param_dtype="bfloat16")
     batch, max_len, page = 4, 1024, 16
@@ -86,6 +92,7 @@ def test_qwen_paged_decode_step_fits_one_v5e(one_chip):
     compiled = jax.jit(decode, donate_argnums=(2,)).lower(
         params, spec((batch, 1)), cache, spec((batch,)),
         spec((batch, nblk)), spec((batch,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # paged_attention
     m = compiled.memory_analysis()
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)

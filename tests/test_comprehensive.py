@@ -11,10 +11,11 @@ from repro.kernels.flash_attention import FAMILY as FLASH
 from repro.kernels.jacobi1d import FAMILY as JACOBI
 from repro.kernels.matadd import FAMILY as MATADD
 from repro.kernels.matmul import FAMILY as MATMUL
+from repro.kernels.paged_attention import FAMILY as PAGED
 from repro.kernels.ssd_scan import FAMILY as SSD
 from repro.kernels.transpose import FAMILY as TRANSPOSE
 
-FAMILIES = [MATMUL, MATADD, JACOBI, TRANSPOSE, FLASH, SSD]
+FAMILIES = [MATMUL, MATADD, JACOBI, TRANSPOSE, FLASH, SSD, PAGED]
 
 
 @pytest.fixture(scope="module", params=FAMILIES, ids=lambda f: f.name)

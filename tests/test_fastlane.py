@@ -27,6 +27,8 @@ SHAPES = {
     "transpose": {"M": 512, "N": 512},
     "flash_attention": {"SQ": 256, "HD": 64},
     "ssd_scan": {"SQ": 256, "HD": 64, "STATE": 64},
+    "paged_attention": {"B": 4, "NK": 4, "GROUP": 2, "HD": 64, "PS": 16,
+                        "NBLK": 8},
 }
 
 

@@ -445,6 +445,40 @@ def test_request_stamps_are_ordered_and_admit_survives_preemption(
         assert reqs[rid].t_admit == admits[rid][0] < admits[rid][-1]
 
 
+def test_tick_span_counts_the_kv_pages_decode_rows_own(smoke_model):
+    """``kv_pages_read`` is the pages the decoding rows own, the sum of
+    ``ceil((pos + 1) / page_size)`` over the rows the decode step masks
+    in; ``kv_pages_table`` is the whole ``max_batch x blocks_per_seq``
+    table the gather path reads.  The CPU engine gathers."""
+    eng = _engine(smoke_model, max_batch=3)
+    decode, calls = eng._decode, []
+
+    def spy(params, last_tok, cache, index, tables, mask):
+        calls.append((np.asarray(index), np.asarray(mask)))
+        return decode(params, last_tok, cache, index, tables, mask)
+
+    eng._decode = spy
+    for i, p in enumerate(_prompts(smoke_model, 4, 7)):
+        eng.submit(p[:5 + 4 * i], max_new=6 + i)
+    with tracing(capacity=1 << 12) as rec:
+        for _ in range(100):
+            eng.step()
+            if not eng.sched.has_work():
+                break
+    spans = [r for r in rec.records() if r["etype"] == "tick_span"]
+    decoded = [s for s in spans if s["decode_rows"]]
+    assert len(decoded) == len(calls) > 5
+    table = eng.max_batch * eng.blocks_per_seq
+    for span, (index, mask) in zip(decoded, calls):
+        want = sum(-(-(int(i) + 1) // eng.page_size) for i in index[mask])
+        assert span["kv_pages_read"] == want > 0
+        assert span["kv_pages_table"] == table
+        assert span["decode_kernel"] is False
+    assert max(s["kv_pages_read"] for s in decoded) > eng.max_batch
+    assert all(s["kv_pages_read"] == s["kv_pages_table"] == 0
+               for s in spans if not s["decode_rows"])
+
+
 class _Annotation:
     """Stands in for ``jax.profiler.TraceAnnotation``: records each span
     built, and its parent when entered."""

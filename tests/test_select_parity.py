@@ -27,6 +27,7 @@ DIMS = {
     "jacobi1d": ("N",),
     "flash_attention": ("SQ", "HD"),
     "ssd_scan": ("SQ", "HD", "STATE"),
+    "paged_attention": ("B", "NK", "GROUP", "HD", "PS", "NBLK"),
 }
 DIM_VALUES = (1, 7, 127, 128, 500, 1024, 4096, 100000)
 MACHINES = (TPU_V5E, PAPER_M2050)
@@ -51,7 +52,8 @@ def test_all_families_covered_by_dims():
 @pytest.mark.parametrize("name", sorted(DIMS))
 @pytest.mark.parametrize("machine", MACHINES, ids=lambda m: m.name)
 def test_parity_default_shape(name, machine):
-    data = {d: v for d, v in zip(DIMS[name], (1024, 512, 512))}
+    data = {d: v for d, v in zip(DIMS[name],
+                                 (1024, 512, 512, 128, 16, 64))}
     cands = _assert_parity(FAMILIES[name], machine, data)
     if machine is TPU_V5E:
         assert cands, f"no candidates for {name} on {machine.name}"
@@ -59,7 +61,7 @@ def test_parity_default_shape(name, machine):
 
 @pytest.mark.parametrize("name", sorted(DIMS))
 def test_parity_truncation_cap(name):
-    data = {d: v for d, v in zip(DIMS[name], (2048, 128, 256))}
+    data = {d: v for d, v in zip(DIMS[name], (2048, 128, 256, 128, 16, 64))}
     _assert_parity(FAMILIES[name], TPU_V5E, data, max_per_leaf=5)
 
 
