@@ -1,8 +1,10 @@
-"""Whether what the window served is right: the comparison behind ``correct``.
+"""Whether what the window produced is right: the comparison behind
+``correct``.
 
-Once the window has closed, a sample of the finished requests, drawn from
-the seed and always holding the longest, is run through the float32
-reference (:mod:`reference.attn_mlp`) over prompt plus served tokens.  The
+For a serving cell: once the window has closed, a sample of the finished
+requests, drawn from the seed and always holding the longest, is run
+through the configuration's float32 reference (the module its
+``"reference"`` names) over prompt plus served tokens.  The
 number compared is the widest gap by which a served token's reference logit
 lies below the reference's best at that position: 0 where every served
 token is the reference's first choice, small where bfloat16 rounding flips
@@ -13,8 +15,6 @@ from __future__ import annotations
 from typing import List
 
 import numpy as np
-
-from reference import attn_mlp
 
 
 def sample(finished: list, seed: int, k: int) -> list:
@@ -42,13 +42,13 @@ def served_tokens(chosen: list, k: int, length: int):
     return tokens, mask
 
 
-def logit_gaps(model: dict, seed: int, chosen: list, k: int, length: int,
-               control: bool = False):
+def logit_gaps(ref, model: dict, seed: int, chosen: list, k: int,
+               length: int, control: bool = False):
     """Widest gap of the served tokens and, with ``control``, of the
     control's first choices, over the sampled positions; and the number of
     served tokens compared."""
     tokens, mask = served_tokens(chosen, k, length)
-    gap, gap_low = attn_mlp.served_gaps(model, seed, tokens, control)
+    gap, gap_low = ref.served_gaps(model, seed, tokens, control)
     widest = float(gap[mask].max()) if mask.any() else float("nan")
     low = (float(gap_low[mask].max()) if control and mask.any() else None)
     return widest, low, int(mask.sum())

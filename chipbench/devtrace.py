@@ -13,7 +13,9 @@ step, ``chipbench.wait`` while the harness waits for the next arrival.
 """
 from __future__ import annotations
 
+import bisect
 import glob
+import math
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
@@ -24,6 +26,9 @@ TOP = 10
 #: operations that hold others (the layer loop): their time is their
 #: children's, listed on their own
 CONTAINERS = ("%while", "%conditional", "%call")
+#: operations that exchange data between chips (sync or async halves)
+COLLECTIVES = ("all-gather", "reduce-scatter", "all-reduce", "all-to-all",
+               "collective-permute")
 
 
 def load(log_dir: str) -> dict:
@@ -33,13 +38,16 @@ def load(log_dir: str) -> dict:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
     pd = ProfileData.from_file(paths[-1])
+    # of a device plane, only the lines the reductions read
+    keep = lambda plane, line: (not plane.name.startswith("/device:")
+                                or line.name in (OPS, MODULES))
     return {"planes": [
         {"name": plane.name,
          "lines": [{"name": line.name,
                     "events": [[ev.name, float(ev.start_ns),
                                 float(ev.duration_ns)]
                                for ev in line.events]}
-                   for line in plane.lines]}
+                   for line in plane.lines if keep(plane, line)]}
         for plane in pd.planes]}
 
 
@@ -79,14 +87,60 @@ def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
 
 
 def covered(busy: List[Tuple[float, float]], s: float, e: float) -> float:
-    """Length of [s, e] that the merged intervals ``busy`` cover."""
-    return sum(max(0.0, min(e, b1) - max(s, b0)) for b0, b1 in busy)
+    """Length of [s, e] that the merged intervals ``busy`` cover (a sorted
+    list, so only those that reach into [s, e] are visited)."""
+    total = 0.0
+    first = max(0, bisect.bisect_right(busy, (s, math.inf)) - 1)
+    for b0, b1 in busy[first:]:
+        if b0 >= e:
+            break
+        total += max(0.0, min(e, b1) - max(s, b0))
+    return total
+
+
+def overlap(a: List[Tuple[float, float]],
+            b: List[Tuple[float, float]]) -> float:
+    """Length that two lists of merged intervals have in common, in one
+    pass over both."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def is_collective(name: str) -> bool:
+    return any(c in name for c in COLLECTIVES)
+
+
+def exposed(plane: dict, w0: float, w1: float) -> Tuple[float, float]:
+    """(seconds in collective operations, seconds of them that no other
+    operation of the plane overlaps), inside [w0, w1]."""
+    coll, other = [], []
+    for name, s, d in _line(plane, OPS):
+        if s >= w1 or s + d <= w0:
+            continue
+        name = op_name(name)
+        iv = (max(s, w0), min(s + d, w1))
+        if is_collective(name):
+            coll.append(iv)
+        elif not name.startswith(CONTAINERS):
+            other.append(iv)
+    coll = union(coll)
+    total = sum(e - s for s, e in coll)
+    return total / 1e9, (total - overlap(coll, union(other))) / 1e9
 
 
 def reduce(tr: dict) -> Optional[dict]:
-    """Busy and idle time of the devices inside the traced window, device
-    time per jitted program, and where the idle time fell on the host.
-    ``None`` where the trace holds no window or no device operation."""
+    """Busy and idle time of the devices inside the traced window (each
+    averaged over the devices), time in collectives and the part of it no
+    other operation hides (averaged likewise; ``None`` where no device ran
+    one), device time per jitted program, and where the idle time fell on
+    the host.  ``None`` where the trace holds no window or no device
+    operation."""
     win = host_events(tr, (WINDOW,))
     devs = [p for p in device_planes(tr) if _line(p, OPS)]
     if not win or not devs:
@@ -98,6 +152,7 @@ def reduce(tr: dict) -> Optional[dict]:
     modules: Dict[str, List[float]] = defaultdict(list)
     ops: Dict[str, float] = defaultdict(float)
     step_idle: List[float] = []
+    coll = [exposed(plane, w0, w1) for plane in devs]
     for i, plane in enumerate(devs):
         busy = union([(max(s, w0), min(s + d, w1))
                       for _, s, d in _line(plane, OPS)
@@ -129,6 +184,9 @@ def reduce(tr: dict) -> Optional[dict]:
         "window_s": (w1 - w0) / 1e9,
         "busy_s": sum(busy_s) / len(busy_s),
         "devices": len(devs),
+        "collective_s": (sum(c[0] for c in coll) / len(coll)
+                         if any(c[0] for c in coll) else None),
+        "collective_exposed_s": sum(c[1] for c in coll) / len(coll),
         "modules": dict(modules),
         "step_idle_s": step_idle,
         "breakdown": {

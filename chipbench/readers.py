@@ -1,8 +1,10 @@
 """Reductions shared by the metric readers in ``chipbench/metrics/``.
 
-A reader gets the run's record (see ``run.py``) and returns one number, or
-``None`` where the run holds nothing to read; the harness then leaves the
-metric out of its line.
+A reader gets the run's record (see ``run.py`` and the runners,
+``serve.py`` and ``train.py``) and returns one number, or ``None`` where the
+run holds nothing to read; the harness then leaves the metric out of its
+line.  The work count is the configuration's reference module's
+(``run["ref"]``).
 """
 from __future__ import annotations
 
@@ -53,11 +55,11 @@ def tick_work(run: dict, tick: dict, part: str):
     if part == "decode":
         if not tick["decode"]:
             return None
-        return work.step(dm, [(p, 1) for p in tick["decode"]],
-                         len(tick["decode"]))
+        return run["ref"].step(dm, [(p, 1) for p in tick["decode"]],
+                               len(tick["decode"]))
     if tick["prefill"] is None:
         return None
-    return work.step(dm, [tick["prefill"]], 1)
+    return run["ref"].step(dm, [tick["prefill"]], 1)
 
 
 def device_seconds(run: dict, part: str) -> List[float]:
@@ -97,3 +99,43 @@ def mfu(run: dict) -> Optional[float]:
                 if (w := tick_work(run, t, part)) is not None)
     return 100.0 * flops / (red["window_s"] * run["peak"]["flops"]
                             * red["devices"])
+
+
+def whole_steps(run: dict) -> List[dict]:
+    """Training steps of the window that ended inside it."""
+    return [s for s in run["steps"] if s["t1"] <= run["window_s"]]
+
+
+def train_tok_s(run: dict) -> Optional[float]:
+    """Tokens of the window's whole steps over the time from the first
+    one's start to the last one's end."""
+    done = whole_steps(run)
+    if not done:
+        return None
+    return sum(s["tokens"] for s in done) / (done[-1]["t1"] - done[0]["t0"])
+
+
+def traced_steps(run: dict) -> List[dict]:
+    if run["traced"] is None:
+        return []
+    a, b = run["traced"][:2]
+    return [s for s in run["steps"] if s["t0"] >= a and s["t1"] <= b]
+
+
+def train_mfu(run: dict) -> Optional[float]:
+    """Work-count FLOPs of the traced training steps over the traced time
+    times the chips' peak FLOP/s."""
+    red, steps = run["trace"], traced_steps(run)
+    if not red or not steps:
+        return None
+    flops = sum(run["ref"].train_step(run["dims"], s["batch"], s["seq_len"])
+                for s in steps)
+    return 100.0 * flops / (red["window_s"] * run["peak"]["flops"]
+                            * red["devices"])
+
+
+def collective_exposed_share(run: dict) -> Optional[float]:
+    red = run["trace"]
+    if not red or red["window_s"] <= 0 or red["collective_s"] is None:
+        return None
+    return 100.0 * red["collective_exposed_s"] / red["window_s"]

@@ -6,8 +6,10 @@
 Everything runs in this one process.  It refuses to run without a TPU (or
 with fewer chips than the cell asks for), keeps JAX's compile cache inside
 the checkout, builds the cell from the files ``BENCHMARK.json`` names
-(``cell.py``), measures for ``--seconds`` and prints, as the last line of
-standard output, one JSON object: ``correct``, ``attempted``, ``failed``,
+(``cell.py``), hands it to the runner its traffic mix names (``serve.py``
+or ``train.py``: set-up, the window, then the comparison with the
+configuration's reference), and prints, as the last line of standard
+output, one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer ones), ``device``, with ``--trace 1`` ``breakdown``, and last
 ``checks``: each number compared with its limit.  The same numbers end
@@ -15,9 +17,9 @@ standard error.
 
 ``--control 1`` runs the correctness control (the reference in float8) in
 the program's place: its first choices are compared as the served tokens,
-so ``correct`` has to come out false.  The program's own widest gap is then
-printed beside it on standard error.  The benchmark's own runs never ask
-for it.
+or its training steps as the program's, so ``correct`` has to come out
+false.  The program's own numbers are then printed beside it on standard
+error.  The benchmark's own runs never ask for it.
 """
 from __future__ import annotations
 
@@ -26,7 +28,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse                                                  # noqa: E402
-import gc                                                        # noqa: E402
 import json                                                      # noqa: E402
 import os                                                        # noqa: E402
 import shutil                                                    # noqa: E402
@@ -39,10 +40,6 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parent / "src"))
 
 import cell as cells                                             # noqa: E402
-
-
-#: longest the comparison waits, after the window, for requests to finish
-FINISH_CAP_S = 150.0
 
 
 class NoChip(RuntimeError):
@@ -96,27 +93,16 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, control: bool,
     import jax
     import check
     import devtrace
-    import readers
-    import serve
-    from reference import weights as W
 
-    conf = cell.config
+    runner, ref = cells.modules(cell)
     peak = json.loads((HERE / "peaks.json").read_text())
     if devices[0].device_kind not in peak:
         raise KeyError(f"no peaks for device kind {devices[0].device_kind!r} "
                        f"in peaks.json")
     peak = peak[devices[0].device_kind]
-    dm = W.dims(conf)
-    cfg = serve.model_config(conf)
-    params = serve.program_params(cfg, conf, seed)
-    eng = serve.build_engine(cfg, params, conf["engine"])
-    serve.warm_up(eng)
-    arrivals = cell.generator.generate(cell.traffic, seed, seconds,
-                                       dm["vocab"])
-    setup_s = time.perf_counter() - t_start
-
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
-    win = serve.run_window(eng, arrivals, seconds, trace_dir, compiles)
+    record, state = runner.measure(cell, ref, seed, seconds, trace_dir,
+                                   compiles, t_start, devices)
     mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
               for d in devices)
     red = None
@@ -132,10 +118,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, control: bool,
             raise RuntimeError("the trace holds no device operation inside "
                                "the traced window")
 
-    record = {"setup_s": setup_s, "window_s": win["window_s"],
-              "served": win["served"], "due": win["due"],
-              "ticks": win["ticks"], "traced": win["traced"], "trace": red,
-              "dims": dm, "peak": peak}
+    record.update(trace=red, dims=ref.dims(cell.config), peak=peak, ref=ref)
     metrics = {}
     for name, entry in cell.metrics.items():
         v = cell.readers[name].read(record)
@@ -143,24 +126,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, control: bool,
             metrics[name] = {"value": float(v), "unit": entry["unit"]}
 
     # correctness, after the window and with the program's state freed
-    lim = conf["check"]
-    t_fin = serve.finish(eng, win["served"], lim["sequences"], FINISH_CAP_S)
-    chosen = check.sample(check.finished(win["served"]), seed,
-                          lim["sequences"])
-    del eng, params
-    gc.collect()
-    t_ref = time.perf_counter()
-    widest, low, n_tok = check.logit_gaps(conf, seed, chosen,
-                                          lim["sequences"],
-                                          conf["engine"]["max_len"], control)
-    t_ref = time.perf_counter() - t_ref
-    # under the control, its first choices stand in the served tokens' place
-    checks = {"max_logit_gap": {"value": low if control else widest,
-                                "limit": lim["max_logit_gap"]},
-              "failed_requests": {"value": check.failed(win["served"]),
-                                  "limit": 0}}
-    out = {"correct": check.verdict(checks), "attempted": len(win["due"]),
-           "failed": check.failed(win["served"]), "metrics": metrics,
+    checks, attempted, failed, info = runner.check(cell, ref, state, seed,
+                                                   control, devices)
+    out = {"correct": check.verdict(checks), "attempted": attempted,
+           "failed": failed, "metrics": metrics,
            "device": {"platform": devices[0].platform,
                       "kind": devices[0].device_kind,
                       "count": len(jax.devices()),
@@ -169,18 +138,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, control: bool,
         out["device"].update(busy_s=red["busy_s"], window_s=red["window_s"])
         out["breakdown"] = red["breakdown"]
     out["checks"] = checks              # last: the numbers and their limits
-    info = {"tokens_compared": n_tok, "requests_compared": len(chosen),
-            "compiles_in_window": win["compiles_in_window"],
-            "still_waiting_at_close": sum(1 for s in win["due"]
-                                          if not s.times),
-            "program_max_logit_gap": widest,
-            "control_max_logit_gap": low, "reference_s": t_ref,
-            "finish_s": t_fin, "ticks": len(win["ticks"]),
-            "tokens": sum(len(s.times) for s in win["served"]),
-            "ttft_p90_ms": 1e3 * readers.percentile(readers.ttft_s(record),
-                                                    90),
-            "trace_start_s": (win["traced"][0] - win["traced"][2]
-                              if win["traced"] else None)}
     return out, info
 
 

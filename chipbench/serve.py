@@ -1,4 +1,5 @@
-"""Drive the program's ``ServeEngine`` through one cell's window.
+"""The serving runner: drive the program's ``ServeEngine`` through one
+cell's window (a traffic mix without ``"runner"``, or ``"runner": "serve"``).
 
 Set-up builds the weights on the device in one jitted call from the seed,
 builds the engine with the configuration's settings, and runs every program
@@ -6,11 +7,14 @@ the window will use once: each quantized prefill chunk length, the decode
 step, and one tiny request in every slot for the engine's small eager
 updates.  The window is an open loop on the host clock: a request is
 submitted between steps once it is due, and each token is stamped when the
-step that commits it returns.
+step that commits it returns.  Where the engine keeps ticks in flight
+(``async_depth`` > 1), the window's close commits them before it reads the
+clock, so all the work sent counts, over all the time it took.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Any, Dict, List, Optional
 
@@ -18,49 +22,50 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import check as C
 from reference import weights as W
 
+#: longest the comparison waits, after the window, for requests to finish
+FINISH_CAP_S = 150.0
 #: share of the window after which a traced run starts its trace
 TRACE_FROM = 0.5
 TRACE_SECONDS = 3.0
 
 
 def model_config(config: dict):
-    """The program's ``ModelConfig``: its registry entry plus overrides."""
+    """The program's ``ModelConfig``: its registry entry plus overrides (a
+    nested group of settings overridden key by key)."""
     from repro.configs import get_config
     prog = config["program"]
-    return dataclasses.replace(get_config(prog["model"]),
-                               **prog.get("overrides", {}))
+    base = get_config(prog["model"])
+    over = {k: (dataclasses.replace(getattr(base, k), **v)
+                if isinstance(v, dict) else v)
+            for k, v in prog.get("overrides", {}).items()}
+    return dataclasses.replace(base, **over)
 
 
-def program_params(cfg, model: dict, seed: int):
+def program_params(cfg, ref, model: dict, seed: int, shardings=None):
     """The program's parameter tree, filled from the benchmark's weights
-    (:mod:`reference.weights`) in one jitted call, each leaf in the type
-    the program stores it in."""
+    (the recipe of the reference module ``ref``) in one jitted call, each
+    leaf in the type the program stores it in, and laid out by
+    ``shardings`` where given."""
     from repro.models import init_model
-    dm = W.dims(model)
+    dm = ref.dims(model)
     shapes = jax.eval_shape(lambda k: init_model(k, cfg)[0],
                             jax.random.PRNGKey(0))
     layer_dtypes = jax.tree.map(lambda s: s.dtype, shapes["layers"])
 
     def build(key):
         def one(i):
-            p = W.layer(dm, key, i)
-            attn = {k: p[k] for k in ("wq", "wk", "wv", "wo")}
-            if dm["bias"]:
-                attn.update(bq=p["bq"], bk=p["bk"], bv=p["bv"])
-            t = {"ln1": {"scale": p["ln1"]}, "attn": attn,
-                 "ln2": {"scale": p["ln2"]},
-                 "mlp": {"wi": p["wu"], "wg": p["wg"], "wo": p["wd"]}}
+            t = ref.program_layer(dm, ref.layer(dm, key, i))
             return jax.tree.map(lambda v, dt: v.astype(dt), t, layer_dtypes)
 
-        o = W.outside(dm, key)
-        tree = {"embed": {"tok": o["embed"], "out": o["unembed"]},
-                "layers": jax.lax.map(one, jnp.arange(dm["layers"])),
-                "ln_f": {"scale": o["ln_f"]}}
+        tree = dict(W.program_outside(ref.outside(dm, key)),
+                    layers=jax.lax.map(one, jnp.arange(dm["layers"])))
         return jax.tree.map(lambda v, s: v.astype(s.dtype), tree, shapes)
 
-    return jax.block_until_ready(jax.jit(build)(W.seed_key(seed)))
+    return jax.block_until_ready(
+        jax.jit(build, out_shardings=shardings)(W.seed_key(seed)))
 
 
 def chunk_lengths(prefill_chunk: int) -> List[int]:
@@ -192,6 +197,12 @@ def run_window(eng, arrivals, seconds: float, trace_dir: Optional[str],
             if not s.req.done:
                 still.append(s)
         active = still
+    # time is up: send nothing more, commit the ticks still in flight
+    # (``async_depth`` > 1), count their tokens, and read the clock after
+    drain(eng)
+    te = time.perf_counter()
+    for s in active:
+        s.times.extend([te - t0] * (len(s.req.out) - len(s.times)))
     window_s = time.perf_counter() - t0
     if traced is not None and traced[1] is None:
         window_span.__exit__(None, None, None)
@@ -201,6 +212,13 @@ def run_window(eng, arrivals, seconds: float, trace_dir: Optional[str],
                                             if s.due < seconds],
             "ticks": ticks, "window_s": window_s, "traced": traced,
             "compiles_in_window": compiles[0] - compiles_at_start}
+
+
+def drain(eng) -> None:
+    """Commit every tick still in flight and dispatch nothing more: an
+    engine with ``async_depth`` > 1 keeps its newest ticks on the device
+    across ``step``; at ``async_depth`` 1 there are none."""
+    eng.run_until_drained(max_ticks=0)
 
 
 def finish(eng, served: List[Served], k: int, cap_s: float) -> float:
@@ -213,4 +231,63 @@ def finish(eng, served: List[Served], k: int, cap_s: float) -> float:
            and eng.sched.has_work()
            and time.perf_counter() - t0 < cap_s):
         eng.step()
+    drain(eng)
     return time.perf_counter() - t0
+
+
+def measure(cell, ref, seed: int, seconds: float, trace_dir, compiles,
+            t_start: float, devices):
+    """Set-up and the window.  Returns the run's record for the readers and
+    what :func:`check` needs."""
+    conf = cell.config
+    dm = ref.dims(conf)
+    cfg = model_config(conf)
+    eng = build_engine(cfg, program_params(cfg, ref, conf, seed),
+                       conf["engine"])
+    warm_up(eng)
+    arrivals = cell.generator.generate(cell.traffic, seed, seconds,
+                                       dm["vocab"])
+    setup_s = time.perf_counter() - t_start
+    win = run_window(eng, arrivals, seconds, trace_dir, compiles)
+    record = {"setup_s": setup_s, "window_s": win["window_s"],
+              "served": win["served"], "due": win["due"],
+              "ticks": win["ticks"], "traced": win["traced"]}
+    return record, {"eng": eng, "win": win}
+
+
+def check(cell, ref, state: dict, seed: int, control: bool, devices):
+    """After the window: a sample of the finished requests through the
+    reference.  Returns the numbers compared with their limits, the
+    requests attempted and failed, and what else the comparison saw."""
+    import readers
+    conf, win, eng = cell.config, state.pop("win"), state.pop("eng")
+    lim = conf["check"]
+    t_fin = finish(eng, win["served"], lim["sequences"], FINISH_CAP_S)
+    chosen = C.sample(C.finished(win["served"]), seed, lim["sequences"])
+    del eng
+    gc.collect()
+    t_ref = time.perf_counter()
+    widest, low, n_tok = C.logit_gaps(ref, conf, seed, chosen,
+                                      lim["sequences"],
+                                      conf["engine"]["max_len"], control)
+    t_ref = time.perf_counter() - t_ref
+    failed = C.failed(win["served"])
+    # under the control, its first choices stand in the served tokens' place
+    checks = {"max_logit_gap": {"value": low if control else widest,
+                                "limit": lim["max_logit_gap"]},
+              "failed_requests": {"value": failed, "limit": 0}}
+    info = {"tokens_compared": n_tok, "requests_compared": len(chosen),
+            "compiles_in_window": win["compiles_in_window"],
+            "still_waiting_at_close": sum(1 for s in win["due"]
+                                          if not s.times),
+            "program_max_logit_gap": widest,
+            "program_checks": {"max_logit_gap": widest,
+                               "failed_requests": failed},
+            "control_max_logit_gap": low, "reference_s": t_ref,
+            "finish_s": t_fin, "ticks": len(win["ticks"]),
+            "tokens": sum(len(s.times) for s in win["served"]),
+            "ttft_p90_ms": 1e3 * readers.percentile(readers.ttft_s(
+                {"window_s": win["window_s"], "due": win["due"]}), 90),
+            "trace_start_s": (win["traced"][0] - win["traced"][2]
+                              if win["traced"] else None)}
+    return checks, len(win["due"]), failed, info

@@ -36,17 +36,17 @@ def main() -> int:
     args = ap.parse_args()
     import readers
     import serve
-    from reference import weights as W
 
     cell = R.cells.load(args.workload, False)
     R.require_chips(cell.chips)
     compiles = R.start_jax()
     conf = cell.config
+    _, ref = R.cells.modules(cell)
     cfg = serve.model_config(conf)
-    eng = serve.build_engine(cfg, serve.program_params(cfg, conf, args.seed),
-                             conf["engine"])
+    eng = serve.build_engine(
+        cfg, serve.program_params(cfg, ref, conf, args.seed), conf["engine"])
     serve.warm_up(eng)
-    vocab = W.dims(conf)["vocab"]
+    vocab = ref.dims(conf)["vocab"]
     for rate in [float(r) for r in args.rates.split(",")]:
         mix = dict(cell.traffic,
                    arrivals=dict(cell.traffic["arrivals"], rate=rate))

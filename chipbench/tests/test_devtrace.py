@@ -57,6 +57,34 @@ def test_no_window_or_no_device_reads_nothing():
     assert devtrace.reduce(tr) is None
 
 
+def _two_chips():
+    """Two devices: on the first an all-gather half hidden behind a fusion
+    and an exposed reduce-scatter; on the second one exposed all-reduce."""
+    tr = _trace()
+    ops0 = [["fusion.1", 12 * MS, 10 * MS],
+            ["%all-gather-start.3 = bf16[] all-gather-start()", 16 * MS,
+             12 * MS],
+            ["%reduce-scatter.2 = f32[] reduce-scatter()", 40 * MS, 5 * MS],
+            ["%while.1 = () while()", 0, 100 * MS]]
+    ops1 = [["%all-reduce.7 = f32[] all-reduce()", 50 * MS, 4 * MS],
+            ["fusion.9", 60 * MS, 10 * MS]]
+    tr["planes"][1:] = [
+        {"name": f"/device:TPU:{i}", "lines": [{"name": "XLA Ops",
+                                                "events": ops}]}
+        for i, ops in enumerate((ops0, ops1))]
+    return tr
+
+
+def test_collectives_and_their_exposed_part_by_hand():
+    red = devtrace.reduce(_two_chips())
+    # device 0: collectives 16-28 and 40-45 (17 ms), the fusion hides
+    # 16-22 and the loop that holds every op hides nothing: 11 ms exposed;
+    # device 1: 4 ms, all exposed
+    assert red["collective_s"] == pytest.approx((0.017 + 0.004) / 2)
+    assert red["collective_exposed_s"] == pytest.approx((0.011 + 0.004) / 2)
+    assert devtrace.reduce(_trace())["collective_s"] is None
+
+
 RECORDED = sorted((HERE / "tests" / "data").glob("trace_*.json.gz"))
 
 

@@ -128,15 +128,15 @@ def test_the_readers_read_a_tiny_engine_through_the_window(recorder):
     import numpy as np
     import serve
     from conftest import TINY_ENGINE, TINY_MIX, TINY_MODEL, TINY_PROGRAM
-    from reference import weights as W
+    from reference import attn_mlp
     install(FlightRecorder(capacity=1 << 14))
     conf = dict(TINY_MODEL, program=TINY_PROGRAM, engine=TINY_ENGINE)
     cfg = serve.model_config(conf)
-    eng = serve.build_engine(cfg, serve.program_params(cfg, conf, 7),
+    eng = serve.build_engine(cfg, serve.program_params(cfg, attn_mlp, conf, 7),
                              conf["engine"])
     serve.warm_up(eng)
     gen = cells.load_module(HERE / "traffic" / "requests.py")
-    arrivals = gen.generate(TINY_MIX, 7, 2.0, W.dims(conf)["vocab"])
+    arrivals = gen.generate(TINY_MIX, 7, 2.0, attn_mlp.dims(conf)["vocab"])
     win = serve.run_window(eng, arrivals, 2.0, None, [0])
     run = {"window_s": win["window_s"], "served": win["served"],
            "due": win["due"], "ticks": win["ticks"], "traced": None}
