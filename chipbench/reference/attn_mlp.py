@@ -26,15 +26,39 @@ This module is also the architecture's whole interface to the harness, the
 one a configuration file names with ``"reference": "attn_mlp"``; every
 reference module gives the same names:
 
-* ``dims(model)``: the sizes, read from the configuration's published keys;
+* ``dims(model)``: the sizes, read from the configuration's published keys
+  (``layers`` the number of layers);
 * the weight recipe ``layer(dm, key, i)`` and ``outside(dm, key)``;
 * ``program_layer(dm, p)``: one layer's tensors as the program's layer tree;
 * ``block(dm, p, x, low)``: one layer of the plain forward pass;
+* ``logits(dm, out, x, low)``: the final norm and output head over the
+  tensors of ``outside``;
 * ``served_gaps(model, seed, tokens, control)``: the serving comparison;
 * ``train_check(model, seed, batches, job, control, devices)``: the
   training comparison (``training.py``);
 * the work count, ``step(dm, rows, logit_rows)`` for a serving step and
   ``train_step(dm, batch, seq_len)`` for a training step.
+
+Two more names are optional; this module gives neither, and so has the
+defaults (``weights.stacks`` and ``weights.outside_tree``):
+
+* ``program_groups(dm)``: the program's layer stacks in order,
+  ``{key: (indices, kind)}``: the key the stack sits under in the program's
+  tree (``layers``, ``dense_layers``, ...), the layer indices (0 up to
+  ``dm["layers"]``) it stacks, each index in one stack, and the kind of its
+  layers: an object (a module, a class or a namespace) whose own
+  ``layer(dm, key, i)``, ``program_layer(dm, p)`` and ``block(dm, p, x,
+  low)`` draw, name and run one layer of that kind, one recipe per kind,
+  ``i`` the layer's index and possibly traced.  By default one stack
+  ``layers`` of every layer, the module itself the kind;
+* ``program_outside(dm, o)``: the tensors outside the layers (``outside``)
+  under the program's names: a tied head gives no ``embed.out``, a block
+  shared by several layers its own tensors.  By default
+  ``weights.program_outside``: ``embed.tok``, ``embed.out``, ``ln_f.scale``.
+
+The harness builds the program's tree from these (``serve.build_params``),
+and the shared passes here and in ``training.py`` draw and run each layer by
+its stack's kind.
 """
 from __future__ import annotations
 
@@ -207,19 +231,26 @@ def _programs(arch, frozen: tuple):
     def gaps(out, x, nxt):
         """Per position: the reference's best logit less that of the next
         token."""
-        return gap(logits(dm, out, x), nxt)
+        return gap(arch.logits(dm, out, x), nxt)
 
     def gaps_low(out, x, xl, nxt):
         """As ``gaps``, and the same less the logit of the control's first
         choice."""
-        ref = logits(dm, out, x)
-        return gap(ref, nxt), gap(ref, logits(dm, out, xl, low=True).argmax(-1))
+        ref = arch.logits(dm, out, x)
+        return gap(ref, nxt), gap(ref, arch.logits(dm, out, xl,
+                                                   low=True).argmax(-1))
+
+    def stack(kind):
+        return {
+            "layer": jax.jit(lambda key, i: kind.layer(dm, key, i)),
+            "block": jax.jit(lambda p, x: kind.block(dm, p, x)),
+            "block_low": jax.jit(lambda p, x: kind.block(dm, p, x, low=True)),
+        }
 
     return {
         "outside": jax.jit(lambda key: arch.outside(dm, key)),
-        "layer": jax.jit(lambda key, i: arch.layer(dm, key, i)),
-        "block": jax.jit(lambda p, x: arch.block(dm, p, x)),
-        "block_low": jax.jit(lambda p, x: arch.block(dm, p, x, low=True)),
+        "stacks": {name: stack(kind)
+                   for name, (_, kind) in W.stacks(arch, dm).items()},
         "gaps": jax.jit(gaps),
         "gaps_low": jax.jit(gaps_low),
     }
@@ -228,7 +259,8 @@ def _programs(arch, frozen: tuple):
 def teacher_forced_gaps(arch, model: dict, seed: int, tokens: np.ndarray,
                         control: bool = False):
     """Teacher-forced pass of the architecture ``arch`` (a reference module)
-    over ``tokens`` (K, T) with the weights of ``seed``.
+    over ``tokens`` (K, T) with the weights of ``seed``, each layer drawn
+    and run by its stack's kind.
 
     Returns ``(gap, gap_low)``, each (K, T): at position t, the reference's
     best logit less its logit of ``tokens[:, t + 1]`` (the last column is 0),
@@ -242,11 +274,12 @@ def teacher_forced_gaps(arch, model: dict, seed: int, tokens: np.ndarray,
         out = fn["outside"](key)
         x = out["embed"][jnp.asarray(tokens)]
         xl = x
-        for i in range(dm["layers"]):
-            p = fn["layer"](key, jnp.int32(i))
-            x = fn["block"](p, x)
+        for i, name in enumerate(W.layer_stacks(W.stacks(arch, dm))):
+            f = fn["stacks"][name]
+            p = f["layer"](key, jnp.int32(i))
+            x = f["block"](p, x)
             if control:
-                xl = fn["block_low"](p, xl)
+                xl = f["block_low"](p, xl)
         nxt = np.concatenate([tokens[:, 1:], tokens[:, :1]], 1)
         gap = np.zeros((K, T), np.float32)
         gap_low = np.zeros((K, T), np.float32)

@@ -9,11 +9,16 @@ schedule (linear warm-up, then a cosine to ``lr_floor`` of the peak).  It
 returns what the harness compares with the program's own readings: each
 step's loss, the norm of each leaf of the first (clipped) gradient, and
 the norm of each leaf's change over the steps, under the program's leaf
-names (``program_layer``), each stacked leaf taken over all layers.
+names: ``<stack key>/<path>`` of each layer stack's ``program_layer``, each
+stacked leaf taken over the layers of its stack, and the outside tensors
+under the module's ``program_outside`` (``weights.stacks`` and
+``weights.outside_tree`` give the defaults).
 
 It imports nothing of the program.  The pass runs over the given devices,
 rows split between them: layer by layer with the inputs of each layer
-kept, then back through each layer's gradient; weights, moments and
+kept, each layer drawn and run by its stack's kind, then back through each
+layer's gradient; the output head is the module's ``logits``, so a head
+tied to the embedding passes its gradient to it; weights, moments and
 gradients are kept split along their first axis between the devices.
 Every matrix product runs at ``Precision.HIGHEST``.  With ``control`` the
 architecture's blocks run their float8 form (``low=True``).
@@ -23,7 +28,7 @@ from __future__ import annotations
 import functools
 import math
 import time
-from typing import Dict, List
+from typing import List
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +36,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import weights as W
-from .attn_mlp import LOGIT_BLOCK, mm, rmsnorm
+from .attn_mlp import LOGIT_BLOCK
 
 
 def lr_at(job: dict, step: int) -> float:
@@ -43,17 +48,18 @@ def lr_at(job: dict, step: int) -> float:
     return peak * (floor + (1 - floor) * 0.5 * (1 + math.cos(math.pi * frac)))
 
 
-def head_loss(dm: dict, out: dict, x: jax.Array, labels: jax.Array,
+def head_loss(arch, dm: dict, out: dict, x: jax.Array, labels: jax.Array,
               z_loss: float, low: bool = False) -> jax.Array:
     """Mean NLL + ``z_loss`` * mean log-normaliser squared, over every
-    position; logits in blocks of positions, recomputed for the gradient."""
+    position; logits (``arch.logits`` over the outside tensors ``out``) in
+    blocks of positions, recomputed for the gradient."""
     B, S, _ = x.shape
     blk = min(LOGIT_BLOCK, S)
     assert S % blk == 0, (S, blk)
 
     @jax.checkpoint
     def part(xb, lb):
-        lg = mm(rmsnorm(xb, out["ln_f"], dm["eps"]), out["unembed"], low)
+        lg = arch.logits(dm, out, xb, low)
         logz = jax.nn.logsumexp(lg, axis=-1)
         gold = jnp.take_along_axis(lg, lb[..., None], -1)[..., 0]
         return jnp.stack([jnp.sum(logz - gold), jnp.sum(logz * logz)])
@@ -77,21 +83,33 @@ def _programs(arch, frozen: tuple, low: bool, mesh: Mesh, z_loss: float):
         return NamedSharding(mesh, spec)
 
     key0 = W.seed_key(0)
-    lay = jax.tree.map(split, jax.eval_shape(lambda: arch.layer(dm, key0, 0)))
     out = jax.tree.map(split, jax.eval_shape(lambda: arch.outside(dm, key0)))
-    hd = {k: out[k] for k in ("ln_f", "unembed")}
 
     def whole(t):
         return jax.lax.with_sharding_constraint(
             t, jax.tree.map(lambda _: rep, t))
 
-    def bwd(p, x, dy):
-        _, vjp = jax.vjp(lambda p, x: arch.block(dm, p, x, low), whole(p), x)
-        return vjp(dy)
+    def stack(first, kind):
+        """One stack's draw, forward and backward through its kind."""
+        lay = jax.tree.map(split, jax.eval_shape(
+            lambda: kind.layer(dm, key0, first)))
+
+        def bwd(p, x, dy):
+            _, vjp = jax.vjp(lambda p, x: kind.block(dm, p, x, low),
+                             whole(p), x)
+            return vjp(dy)
+
+        return {
+            "layer": jax.jit(lambda k, i: kind.layer(dm, k, i),
+                             out_shardings=lay),
+            "fwd": jax.jit(lambda p, x: kind.block(dm, whole(p), x, low),
+                           out_shardings=rows),
+            "bwd": jax.jit(bwd, out_shardings=(lay, rows)),
+        }
 
     def head(o, x, labels):
         return jax.value_and_grad(
-            lambda o, x: head_loss(dm, o, x, labels, z_loss, low),
+            lambda o, x: head_loss(arch, dm, o, x, labels, z_loss, low),
             argnums=(0, 1))(whole(o), x)
 
     def sq(t):
@@ -115,39 +133,38 @@ def _programs(arch, frozen: tuple, low: bool, mesh: Mesh, z_loss: float):
     zeros = lambda t: jax.tree.map(jnp.zeros_like, t)
     return {
         "rows": rows,
-        "layer": jax.jit(lambda k, i: arch.layer(dm, k, i), out_shardings=lay),
+        "stacks": {name: stack(idx[0], kind)
+                   for name, (idx, kind) in W.stacks(arch, dm).items()},
         "outside": jax.jit(lambda k: arch.outside(dm, k), out_shardings=out),
         "zeros": jax.jit(zeros),
         "embed": jax.jit(lambda e, t: e[t], out_shardings=rows),
-        "fwd": jax.jit(lambda p, x: arch.block(dm, whole(p), x, low),
-                       out_shardings=rows),
-        "bwd": jax.jit(bwd, out_shardings=(lay, rows)),
-        "head": jax.jit(head, out_shardings=(rep, (hd, rows))),
-        "embed_grad": jax.jit(
-            lambda t, dx: jnp.zeros((dm["vocab"], dm["d"]),
-                                    jnp.float32).at[t].add(dx),
-            out_shardings=out["embed"]),
+        "head": jax.jit(head, out_shardings=(rep, (out, rows))),
+        # the head's gradient of the embedding (zero where it is not tied)
+        # and that of each token's row
+        "embed_grad": jax.jit(lambda g, t, dx: g.at[t].add(dx),
+                              out_shardings=out["embed"],
+                              donate_argnums=(0,)),
         "sq": jax.jit(sq),
         "adamw": jax.jit(adamw, donate_argnums=(0, 1, 2)),
         "diff_sq": jax.jit(lambda a, b: sq(jax.tree.map(jnp.subtract, a, b))),
     }
 
 
-def _names(arch, dm: dict, layer_tree: dict, out_tree: dict) -> Dict[str, float]:
-    """Squared norms of one layer's tensors (``layer_tree``) and of the
-    tensors outside the layers, under the program's leaf names."""
-    tree = {}
-    if out_tree is not None:
-        tree.update(W.program_outside(out_tree))
-    if layer_tree is not None:
-        tree["layers"] = arch.program_layer(dm, layer_tree)
+def named(arch, dm: dict, stack, tree) -> dict:
+    """The leaves of ``tree`` under the program's leaf names: one layer's
+    tensors of the stack ``stack`` (``<stack>/<path>``), or with ``stack``
+    None the tensors outside the layers."""
+    if stack is None:
+        tree = W.outside_tree(arch, dm, tree)
+    else:
+        tree = {stack: W.stacks(arch, dm)[stack][1].program_layer(dm, tree)}
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    return {"/".join(k.key for k in path): float(v) for path, v in flat}
+    return {"/".join(k.key for k in path): v for path, v in flat}
 
 
 def _add(acc: dict, more: dict) -> None:
     for k, v in more.items():
-        acc[k] = acc.get(k, 0.0) + v
+        acc[k] = acc.get(k, 0.0) + float(v)
 
 
 def train_check(arch, model: dict, seed: int, batches: List[dict], job: dict,
@@ -166,10 +183,12 @@ def train_check(arch, model: dict, seed: int, batches: List[dict], job: dict,
                    float(job["z_loss"]))
     key = W.seed_key(seed)
     hp = (job["b1"], job["b2"], job["eps"], job["weight_decay"])
-    L = dm["layers"]
+    kinds = W.layer_stacks(W.stacks(arch, dm))
+    per = [fn["stacks"][k] for k in kinds]
+    L = len(kinds)
     losses, grad_sq, change_sq = [], {}, {}
     with jax.default_matmul_precision("highest"):
-        params = [fn["layer"](key, jnp.int32(i)) for i in range(L)]
+        params = [per[i]["layer"](key, jnp.int32(i)) for i in range(L)]
         out = fn["outside"](key)
         m = [fn["zeros"](p) for p in params]
         v = [fn["zeros"](p) for p in params]
@@ -179,14 +198,13 @@ def train_check(arch, model: dict, seed: int, batches: List[dict], job: dict,
             labels = jax.device_put(np.asarray(batch["labels"]), fn["rows"])
             xs = [fn["embed"](out["embed"], tokens)]
             for i in range(L):
-                xs.append(fn["fwd"](params[i], xs[-1]))
-            loss, (g_out, dx) = fn["head"](
-                {k: out[k] for k in ("ln_f", "unembed")}, xs.pop(), labels)
+                xs.append(per[i]["fwd"](params[i], xs[-1]))
+            loss, (g_out, dx) = fn["head"](out, xs.pop(), labels)
             losses.append(float(loss))
             grads = [None] * L
             for i in reversed(range(L)):
-                grads[i], dx = fn["bwd"](params[i], xs.pop(), dx)
-            g_out = dict(g_out, embed=fn["embed_grad"](tokens, dx))
+                grads[i], dx = per[i]["bwd"](params[i], xs.pop(), dx)
+            g_out["embed"] = fn["embed_grad"](g_out["embed"], tokens, dx)
             del dx
             total = sum(float(x) for t in [g_out] + grads
                         for x in jax.tree.leaves(fn["sq"](t)))
@@ -201,15 +219,15 @@ def train_check(arch, model: dict, seed: int, batches: List[dict], job: dict,
                     params[i], m[i], v[i], grads[i], scale, lr, c1, c2, hp)
                 grads[i] = None
                 if s == 0:
-                    _add(grad_sq, _names(arch, dm, gsq, None))
+                    _add(grad_sq, named(arch, dm, kinds[i], gsq))
             out, m_out, v_out, gsq = fn["adamw"](out, m_out, v_out, g_out,
                                                  scale, lr, c1, c2, hp)
             if s == 0:
-                _add(grad_sq, _names(arch, dm, None, gsq))
+                _add(grad_sq, named(arch, dm, None, gsq))
         for i in range(L):
-            _add(change_sq, _names(arch, dm, fn["diff_sq"](
-                params[i], fn["layer"](key, jnp.int32(i))), None))
-        _add(change_sq, _names(arch, dm, None, fn["diff_sq"](
+            _add(change_sq, named(arch, dm, kinds[i], fn["diff_sq"](
+                params[i], per[i]["layer"](key, jnp.int32(i)))))
+        _add(change_sq, named(arch, dm, None, fn["diff_sq"](
             out, fn["outside"](key))))
     return {"loss": losses, "grad_total": grad_total,
             "grad_norms": {k: math.sqrt(v) for k, v in grad_sq.items()},

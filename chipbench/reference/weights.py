@@ -8,7 +8,10 @@ Every value is rounded to bfloat16 as it is drawn, so a served copy
 (bfloat16) and the reference's float32 copy hold the same numbers.
 
 The tensors outside the layers (embedding, output head, final norm) are the
-same for every architecture: :func:`outside`.
+same for every architecture that ties nothing: :func:`outside`, named for the
+program by :func:`program_outside`.  :func:`stacks` and :func:`outside_tree`
+read a module's own layer stacks and names of the outside tensors, or give
+these defaults where it has none.
 """
 from __future__ import annotations
 
@@ -52,7 +55,39 @@ def outside(dm: dict, key: jax.Array) -> dict:
     }
 
 
-def program_outside(o: dict) -> dict:
+def program_outside(dm: dict, o: dict) -> dict:
     """The tensors outside the layers under the program's names."""
     return {"embed": {"tok": o["embed"], "out": o["unembed"]},
             "ln_f": {"scale": o["ln_f"]}}
+
+
+def outside_tree(ref, dm: dict, o: dict) -> dict:
+    """The module ``ref``'s outside tensors ``o`` under the program's names:
+    its own ``program_outside``, or :func:`program_outside`."""
+    return getattr(ref, "program_outside", program_outside)(dm, o)
+
+
+def stacks(ref, dm: dict) -> dict:
+    """The module ``ref``'s layer stacks, ``{key: (indices, kind)}`` in
+    order: its own ``program_groups(dm)``, or the one stack ``layers`` of
+    every layer with the module itself as the kind.  A kind gives
+    ``layer(dm, key, i)``, ``program_layer(dm, p)`` and
+    ``block(dm, p, x, low)``; each layer index belongs to one stack."""
+    if hasattr(ref, "program_groups"):
+        groups = ref.program_groups(dm)
+    else:
+        groups = {"layers": (range(dm["layers"]), ref)}
+    out = {k: (tuple(int(i) for i in idx), kind)
+           for k, (idx, kind) in groups.items()}
+    every = sorted(i for idx, _ in out.values() for i in idx)
+    if every != list(range(dm["layers"])):
+        raise ValueError(f"{ref.__name__}: the stacks {sorted(out)} hold the "
+                         f"layers {every}, not each of 0..{dm['layers'] - 1} "
+                         "once")
+    return out
+
+
+def layer_stacks(groups: dict) -> list:
+    """The key of the stack of each layer, in the order of the layers."""
+    return [k for _, k in sorted((i, k) for k, (idx, _) in groups.items()
+                                 for i in idx)]

@@ -45,27 +45,65 @@ def model_config(config: dict):
 
 
 def program_params(cfg, ref, model: dict, seed: int, shardings=None):
-    """The program's parameter tree, filled from the benchmark's weights
-    (the recipe of the reference module ``ref``) in one jitted call, each
-    leaf in the type the program stores it in, and laid out by
-    ``shardings`` where given."""
+    """The program's parameter tree (``init_model``'s for ``cfg``), filled
+    from the benchmark's weights by :func:`build_params`."""
     from repro.models import init_model
-    dm = ref.dims(model)
     shapes = jax.eval_shape(lambda k: init_model(k, cfg)[0],
                             jax.random.PRNGKey(0))
-    layer_dtypes = jax.tree.map(lambda s: s.dtype, shapes["layers"])
+    return build_params(shapes, ref, model, seed, shardings)
 
-    def build(key):
-        def one(i):
-            t = ref.program_layer(dm, ref.layer(dm, key, i))
-            return jax.tree.map(lambda v, dt: v.astype(dt), t, layer_dtypes)
 
-        tree = dict(W.program_outside(ref.outside(dm, key)),
-                    layers=jax.lax.map(one, jnp.arange(dm["layers"])))
-        return jax.tree.map(lambda v, s: v.astype(s.dtype), tree, shapes)
+def build_params(target, ref, model: dict, seed: int, shardings=None):
+    """The tree ``target`` (shapes and types) filled from the benchmark's
+    weights, the recipe of the reference module ``ref``, in one jitted
+    call: the tensors outside the layers under the module's names, and
+    each of its layer stacks drawn layer by layer (``jax.lax.map``) under
+    its key; each leaf in ``target``'s type, laid out by ``shardings``
+    where given.  Where the trees differ, fails before anything is drawn,
+    naming the first path that differs."""
+    dm = ref.dims(model)
+    groups = W.stacks(ref, dm)
+    key = W.seed_key(seed)
 
-    return jax.block_until_ready(
-        jax.jit(build, out_shardings=shardings)(W.seed_key(seed)))
+    def cast(t, like):
+        return jax.tree.map(lambda v, s: v.astype(s.dtype), t, like)
+
+    def build(key, typed):
+        tree = dict(W.outside_tree(ref, dm, ref.outside(dm, key)))
+        for name, (idx, kind) in groups.items():
+            def one(i, kind=kind, name=name):
+                t = kind.program_layer(dm, kind.layer(dm, key, i))
+                return cast(t, target[name]) if typed else t
+            tree[name] = jax.lax.map(one, jnp.asarray(idx))
+        return cast(tree, target) if typed else tree
+
+    differs = first_difference(
+        jax.eval_shape(lambda k: build(k, False), key), target)
+    if differs:
+        raise ValueError(f"{ref.__name__}: the built parameter tree differs "
+                         f"from the program's at {differs}")
+    return jax.block_until_ready(jax.jit(
+        lambda k: build(k, True), out_shardings=shardings)(key))
+
+
+def first_difference(built, target) -> Optional[str]:
+    """The first path, in order, at which the tree ``built`` and the tree
+    ``target`` differ in a leaf or its shape, with what differs there."""
+    flat = [{jax.tree_util.keystr(p): v.shape for p, v
+             in jax.tree_util.tree_flatten_with_path(t)[0]}
+            for t in (built, target)]
+    for path in sorted(flat[0].keys() | flat[1].keys()):
+        if path not in flat[1]:
+            return f"{path}: built, not in the program's tree"
+        if path not in flat[0]:
+            return f"{path}: in the program's tree, not built"
+        if flat[0][path] != flat[1][path]:
+            return f"{path}: built {flat[0][path]}, the program's " \
+                   f"{flat[1][path]}"
+    if jax.tree.structure(built) != jax.tree.structure(target):
+        return f"the containers: built {jax.tree.structure(built)}, the " \
+               f"program's {jax.tree.structure(target)}"
+    return None
 
 
 def chunk_lengths(prefill_chunk: int) -> List[int]:

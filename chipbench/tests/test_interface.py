@@ -1,6 +1,8 @@
 """Each configuration names its architecture's reference module; the harness
 calls that module's interface and names no architecture itself; moving the
-attention + SwiGLU code into ``reference/attn_mlp.py`` kept every number."""
+attention + SwiGLU code into ``reference/attn_mlp.py``, and building the
+program's tree from each module's layer stacks and outside names, kept every
+number."""
 from __future__ import annotations
 
 import hashlib
@@ -21,11 +23,15 @@ sys.path.insert(0, str(HERE.parent / "src"))
 import cell as cells                                              # noqa: E402
 import serve                                                      # noqa: E402
 from conftest import TINY_MODEL, TINY_PROGRAM                     # noqa: E402
-from reference import attn_mlp                                    # noqa: E402
+from conftest import TINY_HYBRID, TINY_HYBRID_PROGRAM             # noqa: E402
+from reference import attn_mlp, hybrid, training                  # noqa: E402
 from reference import weights as W                                # noqa: E402
 
-INTERFACE = ("dims", "layer", "outside", "program_layer", "block",
+INTERFACE = ("dims", "layer", "outside", "program_layer", "block", "logits",
              "served_gaps", "train_check", "step", "train_step")
+#: names a module may leave out, each with a default (``weights.stacks``,
+#: ``weights.outside_tree``)
+OPTIONAL = ("program_groups", "program_outside")
 SEED = 2 ** 31 + 77
 
 
@@ -51,6 +57,21 @@ def test_every_configuration_names_a_whole_reference(name):
     ref = importlib.import_module(f"reference.{conf['reference']}")
     for fn in INTERFACE:
         assert callable(getattr(ref, fn)), (name, fn)
+    for fn in OPTIONAL:
+        assert not hasattr(ref, fn) or callable(getattr(ref, fn)), (name, fn)
+
+
+@pytest.mark.parametrize("ref", [attn_mlp, hybrid], ids=lambda m: m.__name__)
+def test_a_module_without_the_optional_names_gets_the_defaults(ref):
+    """One stack ``layers`` of every layer, the module its own kind, and
+    the outside tensors as ``embed.tok``, ``embed.out`` and ``ln_f.scale``."""
+    for fn in OPTIONAL:
+        assert not hasattr(ref, fn), fn
+    dm = ref.dims(TINY_HYBRID)
+    assert W.stacks(ref, dm) == {"layers": ((0, 1), ref)}
+    o = {"embed": 1, "unembed": 2, "ln_f": 3}
+    assert W.outside_tree(ref, dm, o) == W.program_outside(dm, o) == \
+        {"embed": {"tok": 1, "out": 2}, "ln_f": {"scale": 3}}
 
 
 @pytest.mark.parametrize("name", cells.all_cells())
@@ -114,3 +135,35 @@ def test_the_move_keeps_the_tiny_tree_gaps_and_work():
         == "d013bb1332914a4a"
     assert attn_mlp.step(attn_mlp.dims(TINY_MODEL), ROWS, 4) == \
         (146590720.0, 1985536.0)
+
+
+# Numbers the parent drew before each module built the program's whole tree,
+# at seed 2**31 + 77: the tiny hybrid tree and served gaps; and hymba-1.5b's
+# training leaf names.
+HYMBA_LEAVES = [
+    "embed/out", "embed/tok", "layers/attn/wk", "layers/attn/wo",
+    "layers/attn/wq", "layers/attn/wv", "layers/ln1/scale",
+    "layers/ln2/scale", "layers/lns/scale", "layers/mlp/wg",
+    "layers/mlp/wi", "layers/mlp/wo", "layers/ssm/a_bias", "layers/ssm/wa",
+    "layers/ssm/wb", "layers/ssm/wc", "layers/ssm/wo", "layers/ssm/wx",
+    "ln_f/scale"]
+
+
+def test_the_stacks_keep_the_hybrid_tree_gaps_and_training_names():
+    cfg = serve.model_config({"program": TINY_HYBRID_PROGRAM})
+    assert digest(serve.program_params(cfg, hybrid, TINY_HYBRID, SEED)) \
+        == "52e10d8f6ef50a53"
+    tokens = np.random.default_rng(5).integers(0, 512, (3, 512)
+                                               ).astype(np.int32)
+    gap, low = hybrid.served_gaps(TINY_HYBRID, SEED, tokens, True)
+    assert (digest(gap), digest(low)) == ("9ac95a5aceb27329",
+                                          "ef40ce015d27b4f4")
+    conf = json.loads((HERE / "configs" / "hymba-1.5b.json").read_text())
+    dm = hybrid.dims(conf)
+    key = W.seed_key(SEED)
+    names = set(training.named(hybrid, dm, None, jax.eval_shape(
+        lambda: hybrid.outside(dm, key))))
+    for stack, (idx, kind) in W.stacks(hybrid, dm).items():
+        names |= set(training.named(hybrid, dm, stack, jax.eval_shape(
+            lambda: kind.layer(dm, key, idx[0]))))
+    assert sorted(names) == HYMBA_LEAVES
